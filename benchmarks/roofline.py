@@ -1,10 +1,13 @@
 """Roofline analysis from the dry-run artifacts (results/dryrun/*.json).
 
-Per (arch × shape × mesh) cell, three terms in seconds:
+Per (arch × shape × mesh) cell, three terms in seconds, with the peaks
+of the record's ``device_kind`` (``PEAKS``):
 
-  compute    = FLOPs_global / (chips × 197e12 bf16 FLOP/s)
-  memory     = traffic_model_bytes / (chips × 819e9 B/s HBM)
-  collective = collective_bytes_per_device / 50e9 B/s link
+  compute    = FLOPs_global / (chips × peak bf16 FLOP/s)
+  memory     = traffic_model_bytes / (chips × peak HBM B/s)
+  collective = collective_bytes_per_device / peak B/s per link
+
+A device kind missing from ``PEAKS`` is an error, not a default.
 
 FLOPs_global comes from the jaxpr walker (scan-trip-count exact, includes
 remat recompute); traffic from the documented analytic model; collective
@@ -18,9 +21,19 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12       # bf16 / chip (v5e-class)
-HBM_BW = 819e9            # B/s / chip
-LINK_BW = 50e9            # B/s / link (ICI)
+# per chip, keyed by jax's device_kind. TPU v5e: Google Cloud
+# documentation "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# of inter-chip interconnect over 4 links.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm": 819e9, "link": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no roofline peaks for device kind {device_kind!r};"
+                       f" known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 SHAPE_TOKENS = {
     "train_4k": 4096 * 256,
@@ -39,10 +52,11 @@ def model_flops(rec: dict) -> float:
 
 
 def analyze(rec: dict) -> dict:
+    peak = peaks(rec.get("device_kind", "unrecorded"))
     chips = 512 if rec["mesh"] == "2x16x16" else 256
-    compute_s = rec["flops_global"] / (chips * PEAK_FLOPS)
-    memory_s = rec.get("traffic_model_bytes", 0) / (chips * HBM_BW)
-    coll_s = rec["collectives"]["total_bytes"] / LINK_BW
+    compute_s = rec["flops_global"] / (chips * peak["flops"])
+    memory_s = rec.get("traffic_model_bytes", 0) / (chips * peak["hbm"])
+    coll_s = rec["collectives"]["total_bytes"] / peak["link"]
     mf = model_flops(rec)
     dominant = max((("compute", compute_s), ("memory", memory_s),
                     ("collective", coll_s)), key=lambda kv: kv[1])[0]

@@ -15,6 +15,8 @@ def main() -> None:
                    bench_skyline, bench_stream, bench_topn, bench_tpch,
                    roofline)
     from .common import write_results
+    from repro import compile_cache
+    compile_cache.enable()
     print("name,us_per_call,derived")
     ok = True
     for mod in (bench_distinct, bench_topn, bench_skyline, bench_engine,

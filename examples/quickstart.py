@@ -10,7 +10,7 @@ unsupported predicate (Ex. 1), DISTINCT (Ex. 2), TOP-N (Ex. 3), JOIN
 import numpy as np
 import jax.numpy as jnp
 
-from repro import QuerySpec, core, run_query
+from repro import QuerySpec, compile_cache, core, run_query
 from repro.query import make_products_ratings
 
 NAMES = {1: "Burger", 2: "Pizza", 3: "Fries", 4: "Jello", 5: "Cheetos"}
@@ -18,6 +18,7 @@ SELLERS = {1: "McCheetah", 2: "Papizza", 3: "JellyFish"}
 
 
 def main():
+    compile_cache.enable()
     products, ratings = make_products_ratings()
 
     print("== Ex.1 FILTER: taste>5 OR (texture>4 AND name LIKE e%s) ==")

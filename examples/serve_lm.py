@@ -13,12 +13,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import get_smoke
 from repro.models import LM
 from repro.serve import RequestCache, ServeEngine
 
 
 def main():
+    compile_cache.enable()
     cfg = get_smoke("qwen3-1.7b")
     lm = LM(cfg)
     params, _ = lm.init(jax.random.key(0))

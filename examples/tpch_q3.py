@@ -27,7 +27,7 @@ import time
 import numpy as np
 import jax.numpy as jnp
 
-from repro import core
+from repro import compile_cache, core
 
 
 def make_tpch(scale: int = 30_000, seed: int = 0):
@@ -171,6 +171,7 @@ def multiq_main():
 
 
 def main():
+    compile_cache.enable()
     customer, orders, lineitem = make_tpch()
     t0 = time.time()
     direct = q3_direct(customer, orders, lineitem)

@@ -31,6 +31,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.query import workloads
 
 
@@ -74,6 +75,7 @@ def _trace_demo(tables, path):
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small tables, CI-sized (the verify.sh run)")

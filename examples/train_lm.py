@@ -16,6 +16,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import get_smoke
 from repro.data import TokenPipeline
 from repro.models import LM
@@ -31,6 +32,7 @@ PRESETS = {
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="quick", choices=PRESETS)
     ap.add_argument("--ckpt-dir", default="results/ckpt_train_lm")

@@ -202,7 +202,8 @@ def main() -> int:
     with socket.socket() as s:  # free port for the coordinator
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
-    env = dict(os.environ, SMOKE_PORT=str(port))
+    # one process per chip: both workers stay on the CPU platform
+    env = dict(os.environ, SMOKE_PORT=str(port), JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (str(ROOT / "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
     procs = [subprocess.Popen(

@@ -1,32 +1,24 @@
-"""Version-portable JAX API shims.
+"""Mesh helpers shared by every ``shard_map`` in the repo.
 
-The container toolchain pins one jax version, but the APIs this repo
-touches moved across 0.4.x → 0.5+: ``shard_map`` graduated from
-jax.experimental (where replication checking is ``check_rep``) to
-``jax.shard_map`` (``check_vma``), and the Pallas TPU compiler params
-class was renamed ``TPUCompilerParams`` → ``CompilerParams`` (see
-repro.kernels.common.compiler_params). Import from here instead of
-feature-testing at every call site.
+``jax.make_mesh`` types its axes ``Explicit``: each array built on such a
+mesh carries its sharding in its type, and eager indexing, reshapes and
+``with_sharding_constraint`` then refuse work that GSPMD would lay out
+itself. The engine places and indexes its own arrays, so it runs every
+mesh it is given with ``Auto`` axes over the same devices.
 """
 from __future__ import annotations
 
 import jax
 
 
+def auto_mesh(mesh):
+    """``mesh`` with every axis ``Auto`` (same devices, same axis names)."""
+    if mesh is None or not mesh.explicit_axes:
+        return mesh
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names)
+
+
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """shard_map across jax versions; `check` maps to check_vma/check_rep.
-
-    The graduation to jax.shard_map and the check_rep → check_vma kwarg
-    rename happened in different releases, so the kwarg is picked from
-    the resolved function's signature, not from where it lives.
-    """
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kwarg = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kwarg: check})
+    """``jax.shard_map`` over ``auto_mesh(mesh)``; ``check`` is check_vma."""
+    return jax.shard_map(f, mesh=auto_mesh(mesh), in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -48,7 +48,7 @@ import numpy as np
 
 from ..constants import NEG, POS
 from .distinct import DistinctState
-from .groupby import _FOLD, _INIT, GroupByState
+from .groupby import _FOLD, GroupByState, acc_dtype, init_value
 from .hashing import hash_mod_dyn, multi_hash
 from .pruning import PruneResult
 from .skyline import _SCORES, SkylineState
@@ -397,7 +397,8 @@ def _groupby_scan_b(streams, q, caps):
              else jnp.ones(keys.shape[0], jnp.bool_))
     d, w = caps["d"], caps["w"]
     fold = _FOLD[caps["agg"]]
-    init_v = jnp.float32(_INIT[caps["agg"]])
+    acc = acc_dtype(values.dtype, caps["agg"])
+    init_v = init_value(caps["agg"], acc)
     w_eff = q["w"]
     last = w_eff - 1
     idx = jnp.arange(w)
@@ -434,10 +435,10 @@ def _groupby_scan_b(streams, q, caps):
         return state, (jnp.bool_(False), ev_k, ev_a, ev_valid)
 
     init = GroupByState(keys=jnp.zeros((d, w), jnp.uint32),
-                        aggs=jnp.full((d, w), init_v, jnp.float32),
+                        aggs=jnp.full((d, w), init_v, acc),
                         valid=jnp.zeros((d, w), jnp.bool_))
     state, (keep, ev_k, ev_a, ev_valid) = jax.lax.scan(
-        body, init, (keys, rows, values.astype(jnp.float32), valid))
+        body, init, (keys, rows, values.astype(acc), valid))
     return PruneResult(keep=keep, state=state,
                        emitted=(ev_k, ev_a, ev_valid))
 

@@ -103,7 +103,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import compat
 from ..constants import NEG
@@ -370,7 +370,8 @@ def _groupby_resume(state, streams, p):
 
 
 def _groupby_init(streams, p):
-    return groupby_init(p["d"], p["w"], p.get("agg", "sum"))
+    return groupby_init(p["d"], p["w"], p.get("agg", "sum"),
+                        streams[1].dtype)
 
 
 def _groupby_merge(st, p):
@@ -583,6 +584,29 @@ def shard_stack(arr: jnp.ndarray, shards: int, fill=0) -> jnp.ndarray:
 
 def _unshard(x: jnp.ndarray, m: int) -> jnp.ndarray:
     return x.reshape((-1,) + x.shape[2:])[:m]
+
+
+def _place_lanes(shard_streams, mesh, axis: str, rec):
+    """Lay the stacked [S, n, ...] lanes out on the mesh, each device
+    holding its S/D contiguous lanes, before pass 1 reads them.
+
+    Annotates ``stream_bytes_per_device`` (stream bytes resident on each
+    mesh device, in mesh order) so a report shows where the stream
+    lives. Streams of a multi-process mesh arrive as global arrays
+    already laid out by the caller and are left as they are.
+    """
+    devices = list(mesh.devices.flat)
+    if any(d.process_index != jax.process_index() for d in devices):
+        return shard_streams
+    sharding = NamedSharding(compat.auto_mesh(mesh), P(axis))
+    placed = tuple(jax.device_put(s, sharding) for s in shard_streams)
+    if rec.active:
+        held = dict.fromkeys((d.id for d in devices), 0)
+        for s in placed:
+            for shard in s.addressable_shards:
+                held[shard.device.id] += shard.data.nbytes
+        rec.annotate(stream_bytes_per_device=list(held.values()))
+    return placed
 
 
 def _pad_axis1(a: jnp.ndarray, pad: int, fill) -> jnp.ndarray:
@@ -1083,6 +1107,8 @@ def engine_prune(algo: str, *streams, options: ExecOptions | None = None,
              else (0,) * len(streams))
     shard_streams = tuple(shard_stack(s, shards, f)
                           for s, f in zip(streams, fills))
+    if mode == "mesh":
+        shard_streams = _place_lanes(shard_streams, mesh, mesh_axis, rec)
     if apply_block is None and mode == "mesh" and spec.chunkable:
         apply_block = DEFAULT_MESH_APPLY_BLOCK
 
@@ -1523,6 +1549,7 @@ def engine_prune_batch(algo: str, queries, *streams,
                      waves=len(plan.waves))
 
     if mode == "mesh":
+        shard_streams = _place_lanes(shard_streams, mesh, mesh_axis, rec)
         p2 = pass2 or "mesh"
         if p2 == "auto":
             # charge the largest wave's resident broadcast; one global

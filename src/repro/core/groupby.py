@@ -10,6 +10,12 @@ exactly Q(D) because the aggregate is associative/commutative.
 
 keep[i]=False means entry i's value was absorbed into switch state; the
 emitted stream (same length m, masked) carries evictions.
+
+The aggregate state takes its dtype from the value column
+(``acc_dtype``): integer-valued columns (and COUNT) aggregate in int32,
+exactly up to 2^31 per group, the way engines hold TPC-H DECIMAL as
+scaled integers; float columns keep f32, which is exact only while
+partial sums stay below 2^24.
 """
 from __future__ import annotations
 
@@ -22,27 +28,47 @@ import jax.numpy as jnp
 from .hashing import hash_mod
 from .pruning import PruneResult
 
-_INIT = {"sum": 0.0, "count": 0.0, "min": 3.4e38, "max": -3.4e38}
 _FOLD = {
     "sum": lambda a, v: a + v,
-    "count": lambda a, v: a + 1.0,
+    "count": lambda a, v: a + 1,
     "min": jnp.minimum,
     "max": jnp.maximum,
 }
+
+
+def acc_dtype(values_dtype, agg: str = "sum"):
+    """Aggregate-state dtype: int32 for COUNT and integer/bool columns,
+    f32 for float columns."""
+    if agg == "count" or not jnp.issubdtype(values_dtype, jnp.floating):
+        return jnp.dtype(jnp.int32)
+    return jnp.dtype(jnp.float32)
+
+
+def init_value(agg: str, dtype):
+    """The aggregate's identity in ``dtype`` (a numpy scalar)."""
+    dtype = jnp.dtype(dtype)
+    if agg in ("sum", "count"):
+        return dtype.type(0)
+    info = (jnp.iinfo if jnp.issubdtype(dtype, jnp.integer)
+            else jnp.finfo)(dtype)
+    return dtype.type(info.max if agg == "min" else info.min)
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class GroupByState:
     keys: jnp.ndarray  # uint32[d, w]
-    aggs: jnp.ndarray  # f32[d, w]
+    aggs: jnp.ndarray  # acc_dtype[d, w]
     valid: jnp.ndarray  # bool[d, w]
 
 
-def groupby_init(d: int, w: int, agg: str = "sum") -> GroupByState:
+def groupby_init(d: int, w: int, agg: str = "sum",
+                 dtype=jnp.float32) -> GroupByState:
+    """Empty cache; ``dtype`` is the value column's dtype."""
+    acc = acc_dtype(dtype, agg)
     return GroupByState(
         keys=jnp.zeros((d, w), jnp.uint32),
-        aggs=jnp.full((d, w), jnp.float32(_INIT[agg]), jnp.float32),
+        aggs=jnp.full((d, w), init_value(agg, acc), acc),
         valid=jnp.zeros((d, w), jnp.bool_),
     )
 
@@ -65,7 +91,8 @@ def groupby_prune(keys: jnp.ndarray, values: jnp.ndarray,
     partials are emitted exactly as in one scan over the concatenation.
     """
     fold = _FOLD[agg]
-    init_v = jnp.float32(_INIT[agg])
+    acc = acc_dtype(values.dtype, agg)
+    init_v = init_value(agg, acc)
     rows = hash_mod(keys, d, seed=seed)
     if valid is None:
         valid = jnp.ones(keys.shape[0], jnp.bool_)
@@ -94,9 +121,9 @@ def groupby_prune(keys: jnp.ndarray, values: jnp.ndarray,
         # entry is always absorbed (pruned); evictions are the traffic
         return state, (jnp.bool_(False), ev_k, ev_a, ev_valid)
 
-    init = groupby_init(d, w, agg) if state is None else state
+    init = groupby_init(d, w, agg, values.dtype) if state is None else state
     state, (keep, ev_k, ev_a, ev_valid) = jax.lax.scan(
-        body, init, (keys, rows, values.astype(jnp.float32), valid))
+        body, init, (keys, rows, values.astype(acc), valid))
     return PruneResult(keep=keep, state=state, emitted=(ev_k, ev_a, ev_valid))
 
 

@@ -38,7 +38,12 @@ def bloom_build(keys: jnp.ndarray, nbits: int, num_hashes: int = 3, seed: int = 
 
 def bloom_query(f: BloomFilter, keys: jnp.ndarray) -> jnp.ndarray:
     idx = multi_hash(keys, f.bits.shape[0], f.num_hashes, seed=f.seed)
-    return jnp.all(f.bits[idx], axis=-1)
+    # one 1-D gather per hash: the TPU compiler takes minutes over a
+    # single [m, H]-index gather at m in the millions, seconds over these
+    ok = f.bits[idx[..., 0]]
+    for h in range(1, f.num_hashes):
+        ok = ok & f.bits[idx[..., h]]
+    return ok
 
 
 @jax.tree_util.register_dataclass

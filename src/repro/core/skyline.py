@@ -37,18 +37,36 @@ from ..constants import NEG
 from .pruning import PruneResult
 
 
-def score_sum(x: jnp.ndarray) -> jnp.ndarray:
-    return jnp.sum(x.astype(jnp.float32), axis=-1)
+def _dim_sum(v: jnp.ndarray, axis: int, keepdims: bool) -> jnp.ndarray:
+    """Σ over the point's dimensions as a left fold: one summation order
+    on every backend and in the Pallas kernels, so scores (and hence
+    which points a switch stage stores) agree bit for bit."""
+    parts = [jax.lax.index_in_dim(v, j, axis, keepdims)
+             for j in range(v.shape[axis])]
+    out = parts[0]
+    for p in parts[1:]:
+        out = out + p
+    return out
 
 
-def score_aph(x: jnp.ndarray) -> jnp.ndarray:
-    """Approximate Product Heuristic: Σ log2~(x_j) (piecewise-linear log2)."""
+def score_sum(x: jnp.ndarray, axis: int = -1,
+              keepdims: bool = False) -> jnp.ndarray:
+    return _dim_sum(x.astype(jnp.float32), axis, keepdims)
+
+
+def score_aph(x: jnp.ndarray, axis: int = -1,
+              keepdims: bool = False) -> jnp.ndarray:
+    """Approximate Product Heuristic: Σ log2~(x_j), where log2~ is the
+    piecewise-linear log2 read off the float's bits: exponent plus
+    mantissa fraction (the TCAM priority-encode). Exact on every
+    backend, unlike a transcendental log2."""
     v = x.astype(jnp.float32)
-    safe = jnp.maximum(v, 1.0)
-    e = jnp.floor(jnp.log2(safe))  # stand-in for the TCAM priority-encode
-    frac = safe / jnp.exp2(e) - 1.0
+    bits = jax.lax.bitcast_convert_type(jnp.maximum(v, 1.0), jnp.int32)
+    e = ((bits >> 23) - 127).astype(jnp.float32)
+    frac = jax.lax.bitcast_convert_type(
+        (bits & 0x7FFFFF) | 0x3F800000, jnp.float32) - 1.0
     lg = jnp.where(v >= 1.0, e + frac, -16.0)
-    return jnp.sum(lg, axis=-1)
+    return _dim_sum(lg, axis, keepdims)
 
 
 _SCORES = {"sum": score_sum, "aph": score_aph}

@@ -149,11 +149,11 @@ class PruneStream:
         if mesh is None:
             mesh = _mesh_for_shards(shards, mesh_axis)
         self.shards = int(shards)
-        self.mesh = mesh
+        self.mesh = compat.auto_mesh(mesh)
         self.axis = mesh_axis
         self._lanes = _mesh_lanes(self.shards, mesh.shape[mesh_axis])
-        self._sharding = NamedSharding(mesh, P(mesh_axis))
-        self._replicated = NamedSharding(mesh, P())
+        self._sharding = NamedSharding(self.mesh, P(mesh_axis))
+        self._replicated = NamedSharding(self.mesh, P())
         self.params = dict(params)
         if apply_block is None and self._spec.chunkable:
             apply_block = DEFAULT_MESH_APPLY_BLOCK

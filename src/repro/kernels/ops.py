@@ -1,17 +1,22 @@
 """Public jit'd dispatch for the Cheetah pruning kernels.
 
-On TPU the Pallas kernels run compiled (interpret=False); elsewhere they
-run in interpret mode so the *kernel bodies* execute (and are validated)
-on CPU. `use_ref=True` routes to the pure-jnp oracles in ref.py (same
-block semantics) — used for differential testing and as a safe fallback.
+On TPU the Pallas kernels run compiled; elsewhere they run in interpret
+mode so the *kernel bodies* execute (and are validated) on CPU.
+``_interpret()`` is the one place that decides: the kernel functions
+take ``interpret`` as a required argument. ``use_ref=True`` routes to
+the pure-jnp oracles in ref.py (same block semantics), for
+differential testing; it is never taken as a fallback.
 
-The two-pass `*_prune_parallel` entry points mirror the engine's
-two_pass/mesh structure kernel-side: grid-parallel pass-1 state
-replicas, a plain-XLA merge, and a grid-parallel scan-free apply. Their
-`use_ref` mirrors share the apply bodies with ``core.engine`` (via
-``apply_merged``) — the same per-device filter the engine's
-mesh-resident pass 2 (``engine_prune(..., pass2="mesh")``) runs on each
-device's resident shard.
+The sequential entry points (``topn_prune``, ``distinct_prune``,
+``skyline_prune``) are the grid-parallel pass-1 kernels with one shard:
+one state replica walking the whole stream. The two-pass
+``*_prune_parallel`` entry points mirror the engine's two_pass/mesh
+structure kernel-side: grid-parallel pass-1 state replicas, a plain-XLA
+merge, and a grid-parallel scan-free apply. Their ``use_ref`` mirrors
+share the apply bodies with ``core.engine`` (via ``apply_merged``) —
+the same per-device filter the engine's mesh-resident pass 2
+(``engine_prune(..., pass2="mesh")``) runs on each device's resident
+shard.
 """
 from __future__ import annotations
 
@@ -22,10 +27,7 @@ from . import parallel, ref
 from .bloom_filter import bloom_build_kernel, bloom_query_kernel
 from .common import NEG, POS
 from .cms_sketch import cms_build_kernel, cms_query_kernel
-from .distinct_prune import distinct_prune_kernel
 from .rle_scan import rle_topn_det_kernel, rle_topn_det_ref
-from .skyline_prune import skyline_prune_kernel
-from .topn_prune import topn_prune_kernel
 
 
 def _interpret() -> bool:
@@ -48,8 +50,9 @@ def distinct_prune(values: jnp.ndarray, *, d: int, w: int, block: int = 256,
     if use_ref:
         keep = ref.distinct_block_ref(v, d=d, w=w, block=block, seed=seed)
     else:
-        keep = distinct_prune_kernel(v, d=d, w=w, block=block, seed=seed,
-                                     interpret=_interpret())
+        keep = parallel.distinct_shard_states_kernel(
+            v, d=d, w=w, shards=1, block=block, seed=seed,
+            interpret=_interpret())[0]
     return keep[:m].astype(bool)
 
 
@@ -59,8 +62,9 @@ def topn_prune(values: jnp.ndarray, *, d: int, w: int, block: int = 256,
     if use_ref:
         keep = ref.topn_block_ref(v, d=d, w=w, block=block, seed=seed)
     else:
-        keep = topn_prune_kernel(v, d=d, w=w, block=block, seed=seed,
-                                 interpret=_interpret())
+        keep = parallel.topn_shard_states_kernel(
+            v, d=d, w=w, shards=1, block=block, seed=seed,
+            interpret=_interpret())[0]
     return keep[:m].astype(bool)
 
 
@@ -79,11 +83,11 @@ def distinct_prune_parallel(values: jnp.ndarray, *, d: int, w: int,
                                                  block=block, seed=seed)
     else:
         it = _interpret()
-        keep1, lo, hi, valid = parallel.distinct_shard_states_kernel(
+        keep1, slots, valid = parallel.distinct_shard_states_kernel(
             v, d=d, w=w, shards=shards, block=block, seed=seed, interpret=it)
-        mlo, mhi, owner = parallel.merge_distinct_states(lo, hi, valid)
+        mslots, owner = parallel.merge_distinct_states(slots, valid)
         keep = parallel.distinct_apply_kernel(
-            v, keep1, mlo, mhi, owner, d=d, shards=shards, block=block,
+            v, keep1, mslots, owner, d=d, shards=shards, block=block,
             seed=seed, interpret=it)
     return keep[:m].astype(bool)
 
@@ -238,6 +242,7 @@ def skyline_prune(points: jnp.ndarray, *, w: int, block: int = 256,
     if use_ref:
         keep = ref.skyline_block_ref(p, w=w, block=block, score=score)
     else:
-        keep = skyline_prune_kernel(p, w=w, block=block, score=score,
-                                    interpret=_interpret())
+        keep = parallel.skyline_shard_states_kernel(
+            p, w=w, shards=1, block=block, score=score,
+            interpret=_interpret())[0]
     return keep[:m].astype(bool)

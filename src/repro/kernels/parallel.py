@@ -1,38 +1,30 @@
 """Grid-parallel Pallas pruning kernels (the engine's two_pass on TPU).
 
-The sequential kernels in topn_prune.py / distinct_prune.py /
-skyline_prune.py carry switch state in a VMEM scratch across grid steps,
-which forces ``dimension_semantics=("arbitrary",)`` — the whole grid
-serializes. Here each grid program owns a *state replica* for one shard
-(a contiguous 1/S slice of the stream), so the grid is declared
-``("parallel",)`` and blocks no longer serialize:
+Each kernel family follows the engine's two-pass structure:
 
-  pass 1  S programs; each streams its shard chunk-by-chunk with the
-          exact block semantics of the sequential kernel (one state
-          insertion per row per chunk) and writes its final state to an
-          output indexed by the program id.
-  merge   plain-XLA fold of the S states (per-row top-w union for
+  pass 1  grid ``(S, n/B)``: program ``(i, j)`` folds block ``j`` of shard
+          ``i`` into that shard's state replica, with the block
+          semantics of ``ref.py`` (decisions against the pre-block
+          state, one insertion per state row per block). The shard axis
+          is ``"parallel"`` and the block axis ``"arbitrary"``, so the
+          state lives in VMEM scratch across a shard's blocks and the
+          final replica is written to the output at the shard's last
+          block. ``shards=1`` is the sequential switch pipeline:
+          ``ops.topn_prune`` / ``distinct_prune`` / ``skyline_prune``.
+  merge   plain-XLA fold of the S replicas (per-row top-w union for
           TOP-N, cache-column union + owner ranks for DISTINCT,
-          dominance-set concat for SKYLINE). This is a tiny [d, S·w]
-          tensor op — bandwidth-trivial next to the stream — so it does
-          not warrant a dedicated kernel; it runs between the two
-          pallas_calls.
-  pass 2  an embarrassingly parallel filter kernel applying the merged
-          state to every block (grid m/B, ``("parallel",)``).
+          dominance-set concat for SKYLINE): a tiny [d, S·w] op next to
+          the stream, run between the two pallas_calls.
+  pass 2  an embarrassingly parallel filter applying the merged state
+          to every block (grid m/B, ``("parallel",)``).
 
-Every kernel has a pure-jnp mirror (vmapped block oracles from ref.py +
-the same merge/apply math) used for differential testing and as the
-CPU-fallback `use_ref` path in ops.py. The mirrors' pass 2 is the
-engine's own scan-free filter body (``core.engine.apply_merged``) — the
-identical code that runs per device in the engine's mesh-resident
-pass 2 — so kernel, mirror and engine can never drift apart.
-Correctness contract matches repro.core.engine two_pass: keep masks are
-supersets of the minimal correct survivor set, not of the sequential
-scan's mask.
-
-VMEM budget per program: the same d×w state as the sequential kernels
-plus one B-entry chunk — the shard length only affects how many chunks
-the in-kernel fori_loop walks, not residency.
+Layouts are those of ``kernels.common``: entries as (1, B) lane rows,
+SKYLINE points as (D, B) (one dimension per sublane), state one hash
+row per sublane. Every kernel has a pure-jnp mirror (vmapped block
+oracles from ref.py + the engine's own scan-free filter,
+``core.engine.apply_merged``) used for differential testing; keep
+masks are supersets of the minimal correct survivor set, not of the
+sequential scan's mask.
 """
 from __future__ import annotations
 
@@ -40,68 +32,94 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.skyline import _SCORES
 from . import ref
-from .common import (NEG, compiler_params, gather_rows, hash_mod,
-                     onehot_f32, split16)
+from .common import (CHUNK, F32_LOW, I32_LOW, NEG, any_rows, as_u32,
+                     compiler_params, gather, hash_mod, lane_iota, onehot,
+                     over_rows, padded_rows, row_max, shift_down)
+
+# pass-1 grid: shard axis parallel, block axis carries the state
+_PASS1 = compiler_params(("parallel", "arbitrary"))
+_PASS2 = compiler_params(("parallel",))
 
 
-def _iota1(n: int) -> jnp.ndarray:
-    """1D iota via 2D broadcast (TPU pallas requires >= 2D iota)."""
-    return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)[:, 0]
+def _stream_spec(block: int, nb: int) -> pl.BlockSpec:
+    """(1, block) row of shard i's j-th block on a (S, nb) grid."""
+    return pl.BlockSpec((1, block), lambda i, j: (0, i * nb + j))
+
+
+def _full_spec(shape: tuple) -> pl.BlockSpec:
+    """The whole array in every program of a 1-D grid."""
+    return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+
+
+def _check_pass1(m: int, shards: int, block: int) -> int:
+    assert m % (shards * block) == 0, "pad to a multiple of shards*block"
+    return m // shards // block
 
 
 # ======================================================= TOP-N (rand, Ex. 7)
-def _topn_shard_kernel(d, w, block, nchunks, seed,
-                       x_ref, keep_ref, sout_ref, s_ref):
-    s_ref[...] = jnp.full_like(s_ref, NEG)
+def _topn_shard_kernel(d, w, block, nb, seed, x_ref, keep_ref, sout_ref,
+                       s_ref):
+    j = pl.program_id(1)
 
-    def chunk(c, carry):
-        x = x_ref[pl.ds(c * block, block)].astype(jnp.float32)
-        lidx = c * block + _iota1(block)  # shard-local stream index
-        rows = hash_mod(lidx.astype(jnp.uint32), d, seed)
-        oh = onehot_f32(rows, d)
-        S = s_ref[...]
-        row_min = S[:, -1]
-        my_min = gather_rows(oh, row_min[:, None])[:, 0]
-        keep_ref[pl.ds(c * block, block)] = (x >= my_min).astype(jnp.int32)
-        cand = jnp.max(jnp.where(oh > 0.5, x[:, None], NEG), axis=0)
-        do = cand > row_min
-        wcols = jax.lax.broadcasted_iota(jnp.int32, (d, w), 1)
-        pos = jnp.sum(cand[:, None] <= S, axis=1)
-        rolled = jnp.concatenate([S[:, :1], S[:, :-1]], axis=1)
-        shifted = jnp.where(wcols > pos[:, None], rolled, S)
-        inserted = jnp.where(wcols == pos[:, None], cand[:, None], shifted)
-        s_ref[...] = jnp.where(do[:, None], inserted, S)
-        return carry
+    @pl.when(j == 0)
+    def _init():
+        s_ref[...] = jnp.full_like(s_ref, NEG)
 
-    jax.lax.fori_loop(0, nchunks, chunk, 0)
-    sout_ref[...] = s_ref[...][None]
+    x = x_ref[...]
+    # rows hash the shard-local stream index
+    rows = hash_mod(as_u32(j * block + lane_iota(block)), d, seed)
+
+    def rows_slice(r0, C, my_min):
+        oh = onehot(rows, r0, C)
+        S = s_ref[pl.ds(r0, C), :]
+        row_min = S[:, w - 1:]
+        my_min = jnp.maximum(my_min, gather(oh, row_min, F32_LOW))
+        # per-row best block candidate → one sorted insert per row
+        cand = row_max(oh, x, NEG)
+        pos = jnp.sum((cand <= S).astype(jnp.int32), axis=1, keepdims=True)
+        wcols = jax.lax.broadcasted_iota(jnp.int32, S.shape, 1)
+        ins = jnp.where(wcols == pos, cand,
+                        jnp.where(wcols > pos, shift_down(S, 1), S))
+        s_ref[pl.ds(r0, C), :] = jnp.where(cand > row_min, ins, S)
+        return my_min
+
+    my_min = over_rows(s_ref.shape[0], rows_slice,
+                       jnp.full(x.shape, F32_LOW, jnp.float32))
+    keep_ref[...] = (x >= my_min).astype(jnp.int32)
+
+    @pl.when(j == nb - 1)
+    def _emit():
+        sout_ref[0] = s_ref[...]
 
 
 @partial(jax.jit, static_argnames=("d", "w", "shards", "block", "seed",
                                    "interpret"))
 def topn_shard_states_kernel(values: jnp.ndarray, *, d: int, w: int,
-                             shards: int, block: int = 256, seed: int = 0,
-                             interpret: bool = True):
-    """Pass 1: per-shard keep int32[m] + states f32[shards, d, w]."""
+                             shards: int, block: int, seed: int,
+                             interpret: bool):
+    """Pass 1: keep int32[m] + per-shard states f32[shards, d, w]."""
     m = values.shape[0]
-    assert m % (shards * block) == 0, "pad to a multiple of shards*block"
-    shard_len = m // shards
-    return pl.pallas_call(
-        partial(_topn_shard_kernel, d, w, block, shard_len // block, seed),
-        grid=(shards,),
-        in_specs=[pl.BlockSpec((shard_len,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((shard_len,), lambda i: (i,)),
-                   pl.BlockSpec((1, d, w), lambda i: (i, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((m,), jnp.int32),
-                   jax.ShapeDtypeStruct((shards, d, w), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((d, w), jnp.float32)],
-        compiler_params=compiler_params(("parallel",)),
+    nb = _check_pass1(m, shards, block)
+    dp = padded_rows(d)
+    keep, states = pl.pallas_call(
+        partial(_topn_shard_kernel, d, w, block, nb, seed),
+        grid=(shards, nb),
+        in_specs=[_stream_spec(block, nb)],
+        out_specs=[_stream_spec(block, nb),
+                   pl.BlockSpec((1, dp, w), lambda i, j: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((1, m), jnp.int32),
+                   jax.ShapeDtypeStruct((shards, dp, w), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((dp, w), jnp.float32)],
+        compiler_params=_PASS1,
         interpret=interpret,
-    )(values.astype(jnp.float32))
+    )(values.astype(jnp.float32).reshape(1, m))
+    return keep.reshape(m), states[:, :d]
 
 
 def merge_topn_states(states: jnp.ndarray, w: int) -> jnp.ndarray:
@@ -111,34 +129,38 @@ def merge_topn_states(states: jnp.ndarray, w: int) -> jnp.ndarray:
     return -jnp.sort(-cols, axis=1)[:, :w]
 
 
-def _topn_apply_kernel(d, block, seed, bpshard,
-                       x_ref, rmin_ref, keep_ref):
-    x = x_ref[...].astype(jnp.float32)
-    c = pl.program_id(0) % bpshard  # chunk index within the owning shard
-    lidx = c * block + _iota1(block)
-    rows = hash_mod(lidx.astype(jnp.uint32), d, seed)
-    my_min = gather_rows(onehot_f32(rows, d), rmin_ref[...][:, None])[:, 0]
+def _topn_apply_kernel(d, block, bpshard, seed, x_ref, rmin_ref, keep_ref):
+    x = x_ref[...]
+    c = pl.program_id(0) % bpshard  # block index within the owning shard
+    rows = hash_mod(as_u32(c * block + lane_iota(block)), d, seed)
+    my_min = over_rows(
+        rmin_ref.shape[0],
+        lambda r0, C, acc: jnp.maximum(
+            acc, gather(onehot(rows, r0, C), rmin_ref[pl.ds(r0, C), :],
+                        F32_LOW)),
+        jnp.full(x.shape, F32_LOW, jnp.float32))
     keep_ref[...] = (x >= my_min).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("d", "shards", "block", "seed",
                                    "interpret"))
 def topn_apply_kernel(values: jnp.ndarray, merged: jnp.ndarray, *, d: int,
-                      shards: int, block: int = 256, seed: int = 0,
-                      interpret: bool = True) -> jnp.ndarray:
+                      shards: int, block: int, seed: int,
+                      interpret: bool) -> jnp.ndarray:
     """Pass 2: keep = value >= merged row minimum. Fully parallel grid."""
     m = values.shape[0]
-    bpshard = m // shards // block
+    dp = padded_rows(d)
+    rmin = jnp.full((dp, 1), NEG, jnp.float32).at[:d, 0].set(merged[:, -1])
     return pl.pallas_call(
-        partial(_topn_apply_kernel, d, block, seed, bpshard),
+        partial(_topn_apply_kernel, d, block, m // shards // block, seed),
         grid=(m // block,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                  pl.BlockSpec((d,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.int32),
-        compiler_params=compiler_params(("parallel",)),
+        in_specs=[pl.BlockSpec((1, block), lambda i: (0, i)),
+                  _full_spec((dp, 1))],
+        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
+        compiler_params=_PASS2,
         interpret=interpret,
-    )(values.astype(jnp.float32), merged[:, -1])
+    )(values.astype(jnp.float32).reshape(1, m), rmin).reshape(m)
 
 
 def topn_parallel_ref(values, *, d, w, shards, block, seed=0):
@@ -158,138 +180,164 @@ def topn_parallel_ref(values, *, d, w, shards, block, seed=0):
 
 
 # ==================================================== DISTINCT (FIFO, Ex. 2)
-def _distinct_shard_kernel(d, w, block, nchunks, seed,
-                           x_ref, keep_ref, lo_out, hi_out, val_out,
-                           slo_ref, shi_ref, val_ref, head_ref):
-    slo_ref[...] = jnp.zeros_like(slo_ref)
-    shi_ref[...] = jnp.zeros_like(shi_ref)
-    val_ref[...] = jnp.zeros_like(val_ref)
-    head_ref[...] = jnp.zeros_like(head_ref)
+def _distinct_shard_kernel(d, w, nb, seed, x_ref, keep_ref, sl_out, vl_out,
+                           slot_ref, val_ref, head_ref):
+    j = pl.program_id(1)
 
-    def chunk(c, carry):
-        x = x_ref[pl.ds(c * block, block)]
-        rows = hash_mod(x, d, seed)
-        oh = onehot_f32(rows, d)
-        g_lo = gather_rows(oh, slo_ref[...])
-        g_hi = gather_rows(oh, shi_ref[...])
-        g_v = gather_rows(oh, val_ref[...])
-        x_lo, x_hi = split16(x)
-        hit = jnp.any((g_lo == x_lo[:, None]) & (g_hi == x_hi[:, None])
-                      & (g_v > 0.5), axis=1)
-        miss = ~hit
-        keep_ref[pl.ds(c * block, block)] = miss.astype(jnp.int32)
-        iota = jax.lax.broadcasted_iota(jnp.float32, (block, 1), 0)[:, 0]
-        big = jnp.float32(block)
-        cand = jnp.where(miss, iota, big)
-        per_row_first = jnp.min(jnp.where(oh > 0.5, cand[:, None], big),
-                                axis=0)
-        first_for_me = gather_rows(oh, per_row_first[:, None])[:, 0]
-        insert = miss & (first_for_me == iota)
-        ins_f = insert.astype(jnp.float32)
-        row_ins = jnp.max(jnp.where(oh > 0.5, ins_f[:, None], 0.0), axis=0)
-        v_lo = jnp.sum(oh * (x_lo * ins_f)[:, None], axis=0)
-        v_hi = jnp.sum(oh * (x_hi * ins_f)[:, None], axis=0)
-        head = head_ref[...]
-        wcols = jax.lax.broadcasted_iota(jnp.int32, (d, w), 1)
-        hmask = (wcols == head[:, None]) & (row_ins[:, None] > 0.5)
-        slo_ref[...] = jnp.where(hmask, v_lo[:, None], slo_ref[...])
-        shi_ref[...] = jnp.where(hmask, v_hi[:, None], shi_ref[...])
-        val_ref[...] = jnp.where(hmask, 1.0, val_ref[...])
-        head_ref[...] = jnp.where(row_ins > 0.5, (head + 1) % w, head)
-        return carry
+    @pl.when(j == 0)
+    def _init():
+        slot_ref[...] = jnp.zeros_like(slot_ref)
+        val_ref[...] = jnp.zeros_like(val_ref)
+        head_ref[...] = jnp.zeros_like(head_ref)
 
-    jax.lax.fori_loop(0, nchunks, chunk, 0)
-    lo_out[...] = slo_ref[...][None]
-    hi_out[...] = shi_ref[...][None]
-    val_out[...] = val_ref[...][None]
+    x = x_ref[...]                       # uint32 fingerprints
+    xi = jax.lax.bitcast_convert_type(x, jnp.int32)
+    rows = hash_mod(x, d, seed)
+    iota = lane_iota(x.shape[1])
+
+    def rows_slice(r0, C, hits):
+        oh = onehot(rows, r0, C)
+        slots = slot_ref[pl.ds(r0, C), :]
+        valid = val_ref[pl.ds(r0, C), :]
+        hit_c = jnp.zeros(x.shape, jnp.bool_)
+        for k in range(w):
+            hit_c = hit_c | ((gather(oh, slots[:, k:k + 1], I32_LOW) == xi)
+                             & (gather(oh, valid[:, k:k + 1], 0) > 0))
+        # one insertion per row per block: the row's first missing entry
+        miss = oh & ~hit_c
+        first = jnp.min(jnp.where(miss, iota, x.shape[1]), axis=1,
+                        keepdims=True)
+        ins = miss & (iota == first)
+        row_ins = any_rows(ins, 1)
+        v = row_max(ins, xi, I32_LOW)
+        head = head_ref[pl.ds(r0, C), :]
+        wcols = jax.lax.broadcasted_iota(jnp.int32, slots.shape, 1)
+        hmask = (wcols == head) & row_ins
+        slot_ref[pl.ds(r0, C), :] = jnp.where(hmask, v, slots)
+        val_ref[pl.ds(r0, C), :] = jnp.where(hmask, 1, valid)
+        head_ref[pl.ds(r0, C), :] = jnp.where(
+            row_ins, jnp.where(head == w - 1, 0, head + 1), head)
+        # counted in int32: Mosaic loops carry no bool vectors
+        return hits + hit_c.astype(jnp.int32)
+
+    hits = over_rows(slot_ref.shape[0], rows_slice,
+                     jnp.zeros(x.shape, jnp.int32))
+    keep_ref[...] = (hits == 0).astype(jnp.int32)
+
+    @pl.when(j == nb - 1)
+    def _emit():
+        sl_out[0] = slot_ref[...]
+        vl_out[0] = val_ref[...]
 
 
 @partial(jax.jit, static_argnames=("d", "w", "shards", "block", "seed",
                                    "interpret"))
 def distinct_shard_states_kernel(values: jnp.ndarray, *, d: int, w: int,
-                                 shards: int, block: int = 256,
-                                 seed: int = 0, interpret: bool = True):
-    """Pass 1: shard-local keep + per-shard (lo, hi, valid) cache states."""
+                                 shards: int, block: int, seed: int,
+                                 interpret: bool):
+    """Pass 1: keep int32[m] + per-shard caches: slots uint32[S, d, w]
+    and valid bool[S, d, w]."""
     m = values.shape[0]
-    assert m % (shards * block) == 0, "pad to a multiple of shards*block"
-    shard_len = m // shards
-    state_spec = pl.BlockSpec((1, d, w), lambda i: (i, 0, 0))
-    state_shape = jax.ShapeDtypeStruct((shards, d, w), jnp.float32)
-    return pl.pallas_call(
-        partial(_distinct_shard_kernel, d, w, block, shard_len // block,
-                seed),
-        grid=(shards,),
-        in_specs=[pl.BlockSpec((shard_len,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((shard_len,), lambda i: (i,)),
-                   state_spec, state_spec, state_spec],
-        out_shape=[jax.ShapeDtypeStruct((m,), jnp.int32),
-                   state_shape, state_shape, state_shape],
-        scratch_shapes=[pltpu.VMEM((d, w), jnp.float32),
-                        pltpu.VMEM((d, w), jnp.float32),
-                        pltpu.VMEM((d, w), jnp.float32),
-                        pltpu.VMEM((d,), jnp.int32)],
-        compiler_params=compiler_params(("parallel",)),
+    nb = _check_pass1(m, shards, block)
+    dp = padded_rows(d)
+    state_spec = pl.BlockSpec((1, dp, w), lambda i, j: (i, 0, 0))
+    state_shape = jax.ShapeDtypeStruct((shards, dp, w), jnp.int32)
+    keep, slots, valid = pl.pallas_call(
+        partial(_distinct_shard_kernel, d, w, nb, seed),
+        grid=(shards, nb),
+        in_specs=[_stream_spec(block, nb)],
+        out_specs=[_stream_spec(block, nb), state_spec, state_spec],
+        out_shape=[jax.ShapeDtypeStruct((1, m), jnp.int32),
+                   state_shape, state_shape],
+        scratch_shapes=[pltpu.VMEM((dp, w), jnp.int32),   # slots
+                        pltpu.VMEM((dp, w), jnp.int32),   # valid
+                        pltpu.VMEM((dp, 1), jnp.int32)],  # FIFO head
+        compiler_params=_PASS1,
         interpret=interpret,
-    )(values)
+    )(values.reshape(1, m))
+    return (keep.reshape(m),
+            jax.lax.bitcast_convert_type(slots[:, :d], jnp.uint32),
+            valid[:, :d] > 0)
 
 
-def merge_distinct_states(lo, hi, valid):
-    """[S, d, w] shard caches -> [d, S*w] union + f32 owner codes.
+def merge_distinct_states(slots, valid):
+    """[S, d, w] shard caches -> [d, S*w] union + int32 owner codes.
 
     Owner code per column: shard_rank + 1 where the slot is valid, else 0
     — lets pass 2 test "cached by a lower-ranked shard" with one compare.
     """
-    S, d, w = lo.shape
+    S, d, w = slots.shape
     cat = lambda a: jnp.moveaxis(a, 0, 1).reshape(d, S * w)
-    owner = jnp.repeat(jnp.arange(S, dtype=jnp.float32) + 1.0, w)
-    owner = jnp.where(cat(valid) > 0.5, owner[None, :], 0.0)
-    return cat(lo), cat(hi), owner
+    owner = jnp.repeat(jnp.arange(1, S + 1, dtype=jnp.int32), w)
+    return cat(slots), jnp.where(cat(valid), owner[None, :], 0)
 
 
-def _distinct_apply_kernel(d, block, seed, bpshard,
-                           x_ref, keep1_ref, mlo_ref, mhi_ref, own_ref,
+def _byte_planes(x: jnp.ndarray) -> list:
+    """uint32 → four f32 byte planes: exact MXU operands at any matmul
+    precision (every value < 256 is a bf16)."""
+    return [jax.lax.bitcast_convert_type((x >> (8 * b)) & np.uint32(0xFF),
+                                         jnp.int32).astype(jnp.float32)
+            for b in range(4)]
+
+
+def _distinct_apply_kernel(d, bpshard, seed, x_ref, keep1_ref, p_ref,
                            keep_ref):
     x = x_ref[...]
-    shard = (pl.program_id(0) // bpshard).astype(jnp.float32)
+    shard = pl.program_id(0) // bpshard + 1     # owner code of this shard
     rows = hash_mod(x, d, seed)
-    oh = onehot_f32(rows, d)
-    g_lo = gather_rows(oh, mlo_ref[...])
-    g_hi = gather_rows(oh, mhi_ref[...])
-    g_own = gather_rows(oh, own_ref[...])
-    x_lo, x_hi = split16(x)
-    dup_lower = jnp.any((g_lo == x_lo[:, None]) & (g_hi == x_hi[:, None])
-                        & (g_own > 0.5) & (g_own < shard + 0.5), axis=1)
+    nplanes, K = p_ref.shape[1], p_ref.shape[2]
+    C = p_ref.shape[3]
+
+    def rows_slice(c, acc):
+        # one-hot matmul gathers each entry's row of the K merged columns
+        oh = onehot(rows, c * C, C).astype(jnp.float32)
+        return tuple(
+            a + jnp.dot(p_ref[c, q], oh, preferred_element_type=jnp.float32)
+            for q, a in enumerate(acc))
+
+    zero = jnp.zeros((K, x.shape[1]), jnp.float32)
+    g = jax.lax.fori_loop(0, p_ref.shape[0], rows_slice, (zero,) * nplanes)
+    same = g[4] > 0.5
+    for gb, xb in zip(g[:4], _byte_planes(x)):
+        same = same & (gb == xb)
+    dup_lower = any_rows(same & (g[4] < shard - 0.5), 0)
     keep_ref[...] = ((keep1_ref[...] > 0) & ~dup_lower).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("d", "shards", "block", "seed",
                                    "interpret"))
-def distinct_apply_kernel(values, keep1, mlo, mhi, owner, *, d: int,
-                          shards: int, block: int = 256, seed: int = 0,
-                          interpret: bool = True) -> jnp.ndarray:
+def distinct_apply_kernel(values, keep1, mslots, owner, *, d: int,
+                          shards: int, block: int, seed: int,
+                          interpret: bool) -> jnp.ndarray:
     """Pass 2: drop shard-kept entries cached by a lower-ranked shard."""
     m = values.shape[0]
-    Sw = mlo.shape[1]
-    bpshard = m // shards // block
-    full = lambda a, b: pl.BlockSpec((a, b), lambda i: (0, 0))
+    assert shards < 256, "owner codes must stay exact MXU operands"
+    K = -(-mslots.shape[1] // 8) * 8
+    dp = padded_rows(d)
+    C = min(dp, CHUNK)
+    # [nchunks, 5, K, C]: byte planes + owner codes, transposed so the
+    # gather is a plain (K, C) @ (C, B) matmul per row slice
+    pad = lambda a: jnp.zeros((dp, K), a.dtype).at[:d, :a.shape[1]].set(a)
+    planes = jnp.stack(_byte_planes(pad(mslots))
+                       + [pad(owner).astype(jnp.float32)])
+    planes = planes.reshape(5, dp // C, C, K).transpose(1, 0, 3, 2)
     return pl.pallas_call(
-        partial(_distinct_apply_kernel, d, block, seed, bpshard),
+        partial(_distinct_apply_kernel, d, m // shards // block, seed),
         grid=(m // block,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                  pl.BlockSpec((block,), lambda i: (i,)),
-                  full(d, Sw), full(d, Sw), full(d, Sw)],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.int32),
-        compiler_params=compiler_params(("parallel",)),
+        in_specs=[pl.BlockSpec((1, block), lambda i: (0, i)),
+                  pl.BlockSpec((1, block), lambda i: (0, i)),
+                  _full_spec(planes.shape)],
+        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
+        compiler_params=_PASS2,
         interpret=interpret,
-    )(values, keep1, mlo, mhi, owner)
+    )(values.reshape(1, m), keep1.reshape(1, m), planes).reshape(m)
 
 
 def distinct_parallel_ref(values, *, d, w, shards, block, seed=0):
     """jnp mirror: vmapped FIFO block oracle + the engine's cache-union
     apply body (same "cached by a lower-ranked shard" rule as the
-    kernel's owner codes), on the exact uint32 fingerprints instead of
-    split16 halves."""
+    kernel's owner codes)."""
     from ..core.engine import DistinctMerged, _cols_by_shard, apply_merged
 
     m = values.shape[0]
@@ -307,76 +355,90 @@ def distinct_parallel_ref(values, *, d, w, shards, block, seed=0):
 
 
 # ===================================================== SKYLINE (Ex. 6)
-def _skyline_shard_kernel(w, D, mode, block, nchunks,
-                          x_ref, keep_ref, p_out, s_out, p_ref, s_ref):
-    from .skyline_prune import _score
+def _dominated(x, P, S):
+    """(1, B): some valid stored point dominates the entry. x (D, B),
+    P (K, D) stored points, S (K, 1) scores (NEG = empty slot)."""
+    le = lt = None
+    for j in range(x.shape[0]):
+        xj, pj = x[j:j + 1, :], P[:, j:j + 1]
+        le = (xj <= pj) if le is None else le & (xj <= pj)
+        lt = (xj < pj) if lt is None else lt | (xj < pj)
+    return any_rows(le & lt & (S > NEG), 0)
 
-    p_ref[...] = jnp.zeros_like(p_ref)
-    s_ref[...] = jnp.full_like(s_ref, NEG)
 
-    def chunk(c, carry):
-        x = x_ref[pl.ds(c * block, block)]
-        B = x.shape[0]
-        P, S = p_ref[...], s_ref[...]
-        dom = (jnp.all(x[:, None, :] <= P[None], axis=-1)
-               & jnp.any(x[:, None, :] < P[None], axis=-1)
-               & (S > NEG)[None, :])
-        keep_ref[pl.ds(c * block, block)] = \
-            (~jnp.any(dom, axis=1)).astype(jnp.int32)
-        hx = _score(x, mode)
-        idxw = jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0)[:, 0]
-        for _ in range(w):
-            best = jnp.max(hx)
-            sel = (hx == best)
-            iota = jax.lax.broadcasted_iota(jnp.float32, (B, 1), 0)[:, 0]
-            first = jnp.min(jnp.where(sel, iota, jnp.float32(B)))
-            pick = sel & (iota == first)
-            bx = jnp.sum(jnp.where(pick[:, None], x, 0.0), axis=0)
-            do = best > S[-1]
-            pos = jnp.sum(best <= S)
-            rolledP = jnp.concatenate([P[:1], P[:-1]], axis=0)
-            rolledS = jnp.concatenate([S[:1], S[:-1]], axis=0)
-            P2 = jnp.where((idxw == pos)[:, None], bx[None, :],
-                           jnp.where((idxw > pos)[:, None], rolledP, P))
-            S2 = jnp.where(idxw == pos, best,
-                           jnp.where(idxw > pos, rolledS, S))
-            P = jnp.where(do, P2, P)
-            S = jnp.where(do, S2, S)
-            hx = jnp.where(pick, NEG, hx)
-        p_ref[...] = P
-        s_ref[...] = S
-        return carry
+def _skyline_shard_kernel(w, score, nb, x_ref, keep_ref, p_out, s_out,
+                          p_ref, s_ref):
+    j = pl.program_id(1)
 
-    jax.lax.fori_loop(0, nchunks, chunk, 0)
-    p_out[...] = p_ref[...][None]
-    s_out[...] = s_ref[...][None]
+    @pl.when(j == 0)
+    def _init():
+        p_ref[...] = jnp.zeros_like(p_ref)
+        s_ref[...] = jnp.full_like(s_ref, NEG)
+
+    x = x_ref[...]                               # (D, B)
+    D, B = x.shape
+    keep_ref[...] = (~_dominated(x, p_ref[...], s_ref[...])).astype(
+        jnp.int32)
+    iota = lane_iota(B)
+    iw = jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0)
+    icol = jax.lax.broadcasted_iota(jnp.int32, (w, D), 1)
+
+    def stage(_, carry):  # w switch stages: extract block max, insert
+        P, S, hx = carry
+        best = jnp.max(hx, axis=1, keepdims=True)
+        sel = hx == best
+        first = jnp.min(jnp.where(sel, iota, B), axis=1, keepdims=True)
+        pick = sel & (iota == first)             # ties → lowest index
+        pos = jnp.sum((best <= S).astype(jnp.int32), axis=0, keepdims=True)
+        do = best > S[w - 1:, :]    # beats the last stored score
+        at, after = (iw == pos) & do, (iw > pos) & do
+        S2 = jnp.where(at, best, jnp.where(after, shift_down(S, 0), S))
+        Pd = shift_down(P, 0)
+        P2 = P
+        for k in range(D):
+            bx = jnp.sum(jnp.where(pick, x[k:k + 1, :], 0.0), axis=1,
+                         keepdims=True)
+            colk = jnp.where(at, bx, jnp.where(after, Pd[:, k:k + 1],
+                                               P[:, k:k + 1]))
+            P2 = jnp.where(icol == k, colk, P2)
+        return P2, S2, jnp.where(pick, NEG, hx)
+
+    P, S, _ = jax.lax.fori_loop(
+        0, w, stage, (p_ref[...], s_ref[...],
+                      _SCORES[score](x, axis=0, keepdims=True)))
+    p_ref[...] = P
+    s_ref[...] = S
+
+    @pl.when(j == nb - 1)
+    def _emit():
+        p_out[0] = P
+        s_out[0] = S
 
 
 @partial(jax.jit, static_argnames=("w", "shards", "block", "score",
                                    "interpret"))
 def skyline_shard_states_kernel(points: jnp.ndarray, *, w: int, shards: int,
-                                block: int = 256, score: str = "aph",
-                                interpret: bool = True):
-    """Pass 1: shard-local keep + per-shard (points, scores) stores."""
+                                block: int, score: str, interpret: bool):
+    """Pass 1: keep int32[m] + per-shard stores: points f32[S, w, D],
+    scores f32[S, w]."""
     m, D = points.shape
-    assert m % (shards * block) == 0, "pad to a multiple of shards*block"
-    shard_len = m // shards
-    return pl.pallas_call(
-        partial(_skyline_shard_kernel, w, D, score, block,
-                shard_len // block),
-        grid=(shards,),
-        in_specs=[pl.BlockSpec((shard_len, D), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((shard_len,), lambda i: (i,)),
-                   pl.BlockSpec((1, w, D), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((1, w), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((m,), jnp.int32),
+    nb = _check_pass1(m, shards, block)
+    keep, P, S = pl.pallas_call(
+        partial(_skyline_shard_kernel, w, score, nb),
+        grid=(shards, nb),
+        in_specs=[pl.BlockSpec((D, block), lambda i, j: (0, i * nb + j))],
+        out_specs=[_stream_spec(block, nb),
+                   pl.BlockSpec((1, w, D), lambda i, j: (i, 0, 0)),
+                   pl.BlockSpec((1, w, 1), lambda i, j: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((1, m), jnp.int32),
                    jax.ShapeDtypeStruct((shards, w, D), jnp.float32),
-                   jax.ShapeDtypeStruct((shards, w), jnp.float32)],
+                   jax.ShapeDtypeStruct((shards, w, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((w, D), jnp.float32),
-                        pltpu.VMEM((w,), jnp.float32)],
-        compiler_params=compiler_params(("parallel",)),
+                        pltpu.VMEM((w, 1), jnp.float32)],
+        compiler_params=_PASS1,
         interpret=interpret,
-    )(points.astype(jnp.float32))
+    )(points.astype(jnp.float32).T)
+    return keep.reshape(m), P, S[..., 0]
 
 
 def merge_skyline_states(points, scores):
@@ -386,31 +448,27 @@ def merge_skyline_states(points, scores):
 
 
 def _skyline_apply_kernel(x_ref, p_ref, s_ref, keep_ref):
-    x = x_ref[...]
-    P, S = p_ref[...], s_ref[...]
-    dom = (jnp.all(x[:, None, :] <= P[None], axis=-1)
-           & jnp.any(x[:, None, :] < P[None], axis=-1)
-           & (S > NEG)[None, :])
-    keep_ref[...] = (~jnp.any(dom, axis=1)).astype(jnp.int32)
+    keep_ref[...] = (~_dominated(x_ref[...], p_ref[...], s_ref[...])
+                     ).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
-def skyline_apply_kernel(points, mpoints, mscores, *, block: int = 256,
-                         interpret: bool = True) -> jnp.ndarray:
+def skyline_apply_kernel(points, mpoints, mscores, *, block: int,
+                         interpret: bool) -> jnp.ndarray:
     """Pass 2: keep a point iff no merged stored point dominates it."""
     m, D = points.shape
-    Sw = mpoints.shape[0]
+    K = mpoints.shape[0]
     return pl.pallas_call(
         _skyline_apply_kernel,
         grid=(m // block,),
-        in_specs=[pl.BlockSpec((block, D), lambda i: (i, 0)),
-                  pl.BlockSpec((Sw, D), lambda i: (0, 0)),
-                  pl.BlockSpec((Sw,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.int32),
-        compiler_params=compiler_params(("parallel",)),
+        in_specs=[pl.BlockSpec((D, block), lambda i: (0, i)),
+                  _full_spec((K, D)), _full_spec((K, 1))],
+        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
+        compiler_params=_PASS2,
         interpret=interpret,
-    )(points.astype(jnp.float32), mpoints, mscores)
+    )(points.astype(jnp.float32).T, mpoints,
+      mscores.reshape(K, 1)).reshape(m)
 
 
 def skyline_parallel_ref(points, *, w, shards, block, score="aph"):

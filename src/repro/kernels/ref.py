@@ -1,7 +1,7 @@
 """Pure-jnp oracles for the Pallas pruning kernels.
 
 These implement the kernels' *block semantics* exactly (same math, plain
-gathers instead of one-hot matmuls) so tests can assert allclose/equal.
+gathers instead of one-hot masks) so tests can assert allclose/equal.
 Block semantics = the paper's §9 multi-entry-per-packet rule: per block,
 prune decisions use the pre-block state; at most one state insertion per
 row per block (conservative, correctness-preserving).

@@ -301,6 +301,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
             collectives=coll,
             params=cfg.param_count(),
             active_params=cfg.active_param_count(),
+            device_kind=mesh.devices.flat[0].device_kind,
             **extra,
         )
         print(f"[dryrun] {cell_id}: OK flops={flops_global:.3e} "

@@ -14,7 +14,9 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from .. import compat
 
 # --------------------------------------------------------------- rules
 @dataclasses.dataclass(frozen=True)
@@ -120,7 +122,12 @@ def constrain(x: jnp.ndarray, axes: tuple, rules: "Rules | None") -> jnp.ndarray
     mapped = [rules.act.get(a) if a is not None else None for a in axes]
     mapped = [m if _divisible(x.shape[i], m, rules.sizes) else None
               for i, m in enumerate(mapped)]
-    return jax.lax.with_sharding_constraint(x, P(*_dedupe(mapped)))
+    spec = P(*_dedupe(mapped))
+    if rules.mesh is not None and rules.mesh.explicit_axes:
+        # these models are laid out by GSPMD: constrain over the mesh's
+        # devices with Auto axes (see compat.auto_mesh)
+        spec = NamedSharding(compat.auto_mesh(rules.mesh), spec)
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def tree_specs(axes_tree: Any, table: dict) -> Any:

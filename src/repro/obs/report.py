@@ -184,11 +184,8 @@ class Recorder:
         """In trace mode, block on ``x`` so span walls measure compute,
         not dispatch. Identity otherwise; never changes values."""
         if self.level == "trace" and x is not None:
-            try:
-                import jax
-                jax.block_until_ready(x)
-            except Exception:
-                pass
+            import jax
+            jax.block_until_ready(x)
         return x
 
     def finish(self) -> ExecReport:

@@ -21,10 +21,12 @@ just a timing row:
     predicate decomposition prunes at the switch, master applies the
     full formula and sums survivors.
 
-Exactness is by construction, not tolerance: ``revenue`` is an
-integer-valued float32 (1..50) with per-group sums far below 2^24, so
-f32 addition is exact in any order; ``extprice`` is a permutation
-(all values distinct), so TOP-N has a unique answer; Q6 sums in int64.
+Exactness is by construction, not tolerance: ``revenue`` is an int32
+column (1..50), held the way engines hold TPC-H DECIMAL, so GROUP BY
+sums it in an integer state, exact in any order up to 2^31 per group
+(a Q1 group at SF1 sums to about 23.4M, past f32's exact 2^24);
+``extprice`` is a permutation (all values distinct, exact in f32 up to
+2^24 rows), so TOP-N has a unique answer; Q6 sums in int64.
 
 The generators also back the six per-algorithm tuning beds
 (``engine_streams``): every ``core.ALGORITHMS`` entry gets a stream
@@ -57,7 +59,7 @@ Q6_QTY_LT = 24
 def make_lineitem(scale: int, seed: int = 0) -> Table:
     """Deterministic lineitem-like table with `scale` rows.
 
-    revenue: integer-valued f32 in [1, 50] (exact f32 sums);
+    revenue: int32 in [1, 50] (exact integer GROUP BY sums);
     extprice: a permutation of 1..scale (unique — TOP-N is unambiguous);
     flag: returnflag/linestatus-style 6-value group key;
     discount/quantity: small ints for Q6's conjunctive predicate.
@@ -69,7 +71,7 @@ def make_lineitem(scale: int, seed: int = 0) -> Table:
         "shipdate": jnp.asarray(
             rng.integers(0, DATE_MAX, scale).astype(np.int32)),
         "revenue": jnp.asarray(
-            rng.integers(1, 51, scale).astype(np.float32)),
+            rng.integers(1, 51, scale).astype(np.int32)),
         "extprice": jnp.asarray(
             (rng.permutation(scale) + 1).astype(np.float32)),
         "flag": jnp.asarray(rng.integers(0, 6, scale).astype(np.uint32)),

@@ -168,6 +168,27 @@ def test_groupby_exact(rng, agg):
         assert abs(got[k] - want[k]) < 1e-2 * max(1, abs(want[k]))
 
 
+@pytest.mark.parametrize("path", ["run_query", "run_queries"])
+def test_groupby_integer_sum_exact_past_2_24(path):
+    """An int32 value column aggregates in an int32 state: 2^19 entries
+    of 50 in one group sum to 26,214,400, past f32's exact 2^24, and
+    the serial and the batched path both give Python's exact sum."""
+    from repro.query import QuerySpec, Table, run_queries, run_query
+
+    m = 1 << 19
+    table = Table("t", {"k": jnp.zeros(m, jnp.uint32),
+                        "v": jnp.full(m, 50, jnp.int32)})
+    specs = [QuerySpec("groupby", ("k", "v"), dict(d=8, w=4)),
+             QuerySpec("groupby", ("k", "v"), dict(d=16, w=2))]
+    outs = ([run_query(specs[0], table)] if path == "run_query"
+            else run_queries(specs, table))
+    want = {0: sum([50] * m)}
+    assert want[0] > 1 << 24
+    for out in outs:
+        assert out["output"] == want
+        assert all(isinstance(v, int) for v in out["output"].values())
+
+
 # --------------------------------------------------------------- FILTER
 def test_filter_decomposition(rng):
     cols = {"taste": jnp.asarray(rng.integers(0, 11, 500)),

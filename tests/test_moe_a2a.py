@@ -1,5 +1,6 @@
 """all-to-all expert-parallel MoE vs dense-dispatch baseline (4-dev mesh)."""
 import json
+import os
 import subprocess
 import sys
 
@@ -60,8 +61,7 @@ print("RESULT:" + json.dumps({
 def test_a2a_matches_dense_dispatch():
     proc = subprocess.run([sys.executable, "-c", _SCRIPT],
                           capture_output=True, text=True, timeout=600,
-                          env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                               "HOME": "/root"})
+                          env=dict(os.environ, PYTHONPATH="src"))
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
     out = json.loads(line[0][len("RESULT:"):])

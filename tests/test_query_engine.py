@@ -3,6 +3,7 @@
 Multi-worker correctness runs in a subprocess with 8 host devices so the
 main test process keeps its single-device view (see dryrun.py note)."""
 import json
+import os
 import subprocess
 import sys
 
@@ -99,8 +100,7 @@ print("RESULT:" + json.dumps(out))
 def test_multiworker_8_devices():
     proc = subprocess.run([sys.executable, "-c", _MULTIWORKER],
                           capture_output=True, text=True, timeout=600,
-                          env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                               "HOME": "/root"})
+                          env=dict(os.environ, PYTHONPATH="src"))
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
     out = json.loads(line[0][len("RESULT:"):])
@@ -200,9 +200,26 @@ def test_run_queries_mesh_8_devices():
     collective per group) with results equal to the serial mesh loop."""
     proc = subprocess.run([sys.executable, "-c", _MULTIQ_MESH],
                           capture_output=True, text=True, timeout=600,
-                          env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                               "HOME": "/root"})
+                          env=dict(os.environ, PYTHONPATH="src"))
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
     out = json.loads(line[0][len("RESULT:"):])
     assert out["multiq_mesh_ok"]
+
+
+def test_readme_mesh_example_runs():
+    """README's run_query-on-a-mesh snippet runs as written (it builds
+    its mesh with jax.make_mesh, whose axes are Explicit) and returns
+    the exact top-100."""
+    import pathlib
+    import re
+
+    readme = (pathlib.Path(__file__).parents[1] / "README.md").read_text()
+    block = next(b for b in re.findall(r"```python\n(.*?)```", readme, re.S)
+                 if "jax.make_mesh" in b and "run_query(" in b)
+    scope: dict = {}
+    exec(block, scope)
+    res, uv = scope["res"], scope["uv"]
+    want = np.sort(np.asarray(uv.cols["ad_revenue"]))[-100:]
+    assert np.array_equal(np.sort(np.asarray(res["output"][0])), want)
+    assert 0 < res["pruned_fraction"] < 1

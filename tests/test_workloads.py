@@ -55,7 +55,7 @@ def test_extprice_unique_and_revenue_integer_valued():
     ext = np.asarray(li["extprice"])
     assert len(np.unique(ext)) == ext.shape[0]  # TOP-N unambiguous
     rev = np.asarray(li["revenue"])
-    assert np.array_equal(rev, np.round(rev))   # exact f32 sums
+    assert rev.dtype == np.int32                # exact integer sums
     assert rev.min() >= 1 and rev.max() <= 50
 
 
