@@ -11,7 +11,7 @@ import traceback
 
 def main() -> None:
     from . import (bench_completion, bench_distinct, bench_encoded,
-                   bench_engine, bench_obs, bench_resources, bench_scale,
+                   bench_engine, bench_resources, bench_scale,
                    bench_skyline, bench_stream, bench_topn, bench_tpch,
                    roofline)
     from .common import write_results
@@ -20,8 +20,8 @@ def main() -> None:
     print("name,us_per_call,derived")
     ok = True
     for mod in (bench_distinct, bench_topn, bench_skyline, bench_engine,
-                bench_stream, bench_tpch, bench_encoded, bench_obs,
-                bench_scale, bench_completion, bench_resources, roofline):
+                bench_stream, bench_tpch, bench_encoded, bench_scale,
+                bench_completion, bench_resources, roofline):
         try:
             mod.run()
         except Exception:  # noqa: BLE001

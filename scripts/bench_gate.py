@@ -28,11 +28,7 @@ cases:
               regression.
   ``*_eps``   entries/sec (streaming sustained throughput) — gated
               like ``_qps``.
-  ``*_ratio`` informational ratio — reported, never gated, UNLESS the
-              row declares a ceiling in CEILINGS (within-run ratios of
-              two measurements, e.g. the telemetry overhead contract
-              ``obs_overhead_ratio <= 1.10``: counters-on must stay
-              within 10% of obs-off on the same call at any shape).
+  ``*_ratio`` informational ratio — reported, never gated.
   ``*_count`` resolved integer (lane counts etc.) — reported, never
               gated.
 
@@ -72,21 +68,12 @@ FLOORS = {
     "tpch_tuned_vs_analytic_x": 1.0,
 }
 
-# per-row ceilings for *_ratio rows (within-run, baseline-free — like
-# the _x floors). Rows absent here stay informational.
-CEILINGS = {
-    # the no-instruments-inside-jit contract: obs="counters" adds only
-    # a host mask readback + dict updates, so > 10% overhead means an
-    # instrument leaked onto the hot path
-    "obs_overhead_ratio": 1.10,
-}
-
 # percentile-latency suffixes before the plain "_us" they end with, so
 # classify() names the specific unit; "_eps" gates like "_qps"
 SUFFIXES = ("_p50_us", "_p99_us", "_us", "_x", "_qps", "_eps",
             "_ratio", "_count")
 GATED_PREFIXES = ("engine_", "stream_", "tpch_", "encoded_",
-                  "decode_skipped_", "obs_")
+                  "decode_skipped_")
 
 # must precede any jax import (bench rows depend on the device count)
 if "xla_force_host_platform_device_count" not in os.environ.get(
@@ -113,8 +100,8 @@ def main() -> int:
         print("bench_gate: no committed BENCH_results.json — gating "
               "only the within-run _x floors")
 
-    from benchmarks import (bench_encoded, bench_engine, bench_obs,
-                            bench_stream, bench_tpch, common)
+    from benchmarks import (bench_encoded, bench_engine, bench_stream,
+                            bench_tpch, common)
 
     print("bench_gate: running bench_engine --smoke ...")
     bench_engine.run(smoke=True)
@@ -124,8 +111,6 @@ def main() -> int:
     bench_tpch.run(smoke=True)
     print("bench_gate: running bench_encoded --smoke ...")
     bench_encoded.run(smoke=True)
-    print("bench_gate: running bench_obs --smoke ...")
-    bench_obs.run(smoke=True)
     fresh = dict(common.RESULTS)
 
     failures: list[str] = []
@@ -176,14 +161,6 @@ def main() -> int:
                 failures.append(
                     f"{name}: {val:.1f} {unit} below committed/"
                     f"{THRESHOLD} = {floor:.1f}")
-        elif kind == "_ratio" and name in CEILINGS:
-            ceil = CEILINGS[name]
-            status = "FAIL" if val > ceil else "ok"
-            print(f"bench_gate: {name}: {val:.3f} (ceiling {ceil}) "
-                  f"{status}")
-            if val > ceil:
-                failures.append(
-                    f"{name}: {val:.3f} above the {ceil} ceiling")
         else:  # _ratio / _count: informational
             print(f"bench_gate: {name}: {val:g} ({kind[1:]}) — "
                   "informational")

@@ -800,7 +800,8 @@ def _obs_mask_counts(rec, keep, m: int, *, encoded: bool = False,
     rec.count("entries_scanned", scanned)
     if not getattr(keep, "is_fully_addressable", True):
         return
-    flat = np.asarray(rec.sync(keep)).reshape(queries, -1)[:, :m]
+    with rec.span("to_host"):
+        flat = np.asarray(rec.sync(keep)).reshape(queries, -1)[:, :m]
     kept = int(flat.sum())
     rec.count("entries_kept", kept)
     if encoded and scanned:

@@ -214,6 +214,11 @@ class PruneStream:
             jax.block_until_ready(x)
         return x
 
+    def _name(self, worker, stage: str) -> None:
+        """Name a program body ``<algo>_stream_<stage>``: the profiler
+        then reports it as ``jit_<algo>_stream_<stage>``."""
+        worker.__name__ = worker.__qualname__ = f"{self.algo}_stream_{stage}"
+
     def _init_state(self, example_chunks: tuple):
         lane_streams = tuple(jnp.asarray(c[0, :1]) for c in example_chunks)
         lane = self._spec.init(lane_streams, self.params)
@@ -244,6 +249,8 @@ class PruneStream:
             if r.emitted is None:
                 return r.state, r.keep
             return r.state, r.keep, r.emitted
+
+        self._name(worker, "fold")
 
         local = tuple(
             jax.ShapeDtypeStruct((self._lanes, nb) + shape[2:], dtype)
@@ -278,6 +285,7 @@ class PruneStream:
                                       keep1, p2, block)
             return spec.apply(merged, local, keep1, p2)
 
+        self._name(worker, "apply")
         fn = jax.jit(compat.shard_map(
             worker, self.mesh,
             (P(), P(axis), P()) + (P(axis),) * nstreams, P(axis)))
@@ -295,6 +303,7 @@ class PruneStream:
                 st)
             return spec.merge(gathered, params)
 
+        self._name(worker, "merge")
         self._merge_fn = jax.jit(
             compat.shard_map(worker, self.mesh, P(axis), P()))
         return self._merge_fn
@@ -326,53 +335,57 @@ class PruneStream:
         """
         if self._closed:
             raise RuntimeError("stream is closed")
+        rec = self._rec
         streams = tuple(s for s in streams if s is not None)
         if not self._enc_wrapped and self._decode == "eager":
             streams = _decode_streams(
                 streams, normalize_encodings(self._encoding, len(streams)))
-        np_streams = [np.asarray(s) for s in streams]
-        b = int(np_streams[0].shape[0])
-        if b == 0:
-            raise ValueError("empty micro-batch")
-        S = self.shards
-        nb = -(-b // S)
-        if self._spec.pad_validity and len(np_streams) < 3:
-            # always appended (not just on ragged batches) so every
-            # micro-batch runs the same 3-stream executable and the
-            # lane-view stream matches a one-shot call with the column
-            np_streams.append(np.ones(b, np.bool_))
-        if not self._enc_wrapped and self._decode != "eager":
-            # wrap once, at the final stream count (validity included):
-            # every later fold/merge/apply body decodes in place and the
-            # per-batch ragged pads become code-space fills
-            encs = normalize_encodings(self._encoding, len(np_streams))
-            encs = _padded_encodings(
-                self.algo, self._spec, encs,
-                tuple(jnp.asarray(s[:1]) for s in np_streams), self.params)
-            self._spec = _encoded_spec(self.algo, self._spec, encs)
-            self._enc_wrapped = True
-        pad = S * nb - b
-        if pad:
-            fills = self._spec.pads(tuple(np_streams), self.params)
-            np_streams = [
-                np.concatenate([s, np.broadcast_to(
-                    np.asarray(f).astype(s.dtype, copy=False),
-                    (pad,) + s.shape[1:])])
-                for s, f in zip(np_streams, fills)]
-        chunks_np = tuple(s.reshape((S, nb) + s.shape[1:])
-                          for s in np_streams)
+        with rec.span("prepare"):
+            np_streams = [np.asarray(s) for s in streams]
+            b = int(np_streams[0].shape[0])
+            if b == 0:
+                raise ValueError("empty micro-batch")
+            S = self.shards
+            nb = -(-b // S)
+            if self._spec.pad_validity and len(np_streams) < 3:
+                # always appended (not just on ragged batches) so every
+                # micro-batch runs the same 3-stream executable and the
+                # lane-view stream matches a one-shot call with the column
+                np_streams.append(np.ones(b, np.bool_))
+            if not self._enc_wrapped and self._decode != "eager":
+                # wrap once, at the final stream count (validity
+                # included): every later fold/merge/apply body decodes in
+                # place and the per-batch ragged pads become code-space
+                # fills
+                encs = normalize_encodings(self._encoding, len(np_streams))
+                encs = _padded_encodings(
+                    self.algo, self._spec, encs,
+                    tuple(jnp.asarray(s[:1]) for s in np_streams),
+                    self.params)
+                self._spec = _encoded_spec(self.algo, self._spec, encs)
+                self._enc_wrapped = True
+            pad = S * nb - b
+            if pad:
+                fills = self._spec.pads(tuple(np_streams), self.params)
+                np_streams = [
+                    np.concatenate([s, np.broadcast_to(
+                        np.asarray(f).astype(s.dtype, copy=False),
+                        (pad,) + s.shape[1:])])
+                    for s, f in zip(np_streams, fills)]
+            chunks_np = tuple(s.reshape((S, nb) + s.shape[1:])
+                              for s in np_streams)
         self._last_chunk_shapes = tuple(
             (c.shape, c.dtype) for c in chunks_np)
-        chunks = tuple(self._put(c) for c in chunks_np)
+        off = self._offset
+        with rec.span("to_device"):
+            chunks = tuple(self._put(c) for c in chunks_np)
+            off_arr = self._rep_scalar(off)
         if self._state is None:
             self._state = self._init_state(chunks_np)
         K = self._resolve_merge_k(S * nb, np_streams)
 
-        off = self._offset
-        off_arr = self._rep_scalar(off)
         fold_fn = self._get_fold(nb, len(chunks))
         t = len(self._batches)
-        rec = self._rec
         # spans on the hot path time the *dispatch* only — syncing here
         # would serialize the async pipeline the stream exists for
         with rec.span("fold_dispatch", batch=t, entries=b):
@@ -388,7 +401,9 @@ class PruneStream:
                 self._merged = self._serialize(
                     self._get_merge()(self._state))
             self._count_merge(t)
-        keep_live = self._live_mask(chunks, keep1, off_arr, nb, len(chunks))
+        with rec.span("apply_dispatch"):
+            keep_live = self._live_mask(chunks, keep1, off_arr, nb,
+                                        len(chunks))
         if rec.active:
             rec.count("entries_scanned", b)
             staleness = (t - self._last_merge_t if self._last_merge_t >= 0
@@ -423,7 +438,8 @@ class PruneStream:
         self._drain()
         while len(self._pending) > self.window:
             self.stats["window_blocks"] += 1
-            jax.block_until_ready(self._pending.popleft())
+            with self._rec.span("window_block"):
+                jax.block_until_ready(self._pending.popleft())
             self._drain()
 
     def _drain(self):

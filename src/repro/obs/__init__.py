@@ -1,14 +1,16 @@
-"""Zero-dependency observability for the pruning engine.
+"""Observability for the pruning engine.
 
-Three parts (stdlib only, strictly host-side):
+Three parts (strictly host-side):
 
 * :mod:`repro.obs.metrics` — thread-safe counters / gauges /
-  histograms in a process-wide ``REGISTRY``;
-* :mod:`repro.obs.trace` — nestable wall-clock spans exported as
-  Chrome trace-event JSON (load in Perfetto);
+  histograms in a process-wide ``REGISTRY`` (stdlib only);
+* :mod:`repro.obs.trace` — nestable spans on the JAX profiler's clock
+  (``jax.profiler.TraceAnnotation``), also exported as Chrome
+  trace-event JSON at ``obs="trace"`` (load in Perfetto);
 * :mod:`repro.obs.report` — the per-call ``ExecReport`` that engine
-  entry points attach to their results, and the ``Recorder``/``NULL``
-  machinery behind ``ExecOptions.obs="off"|"counters"|"trace"``.
+  entry points attach to their results, the ``Recorder``/``NULL``
+  machinery behind ``ExecOptions.obs="off"|"counters"|"trace"``, and
+  :func:`span` for code that has no recorder.
 
 Plus :mod:`repro.obs.log`, the structured ``repro.*`` logger that
 replaces ad-hoc ``warnings.warn``/stderr diagnostics (the ``warnings``
@@ -22,7 +24,7 @@ from . import log, metrics, report, trace
 from .log import get_logger
 from .metrics import REGISTRY, Registry
 from .report import (NULL, OBS_MODES, ExecReport, Recorder, default_level,
-                     recorder, set_default_level)
+                     recorder, set_default_level, span)
 from .trace import TRACER, Tracer
 
 __all__ = [
@@ -30,6 +32,6 @@ __all__ = [
     "get_logger",
     "REGISTRY", "Registry",
     "NULL", "OBS_MODES", "ExecReport", "Recorder",
-    "default_level", "recorder", "set_default_level",
+    "default_level", "recorder", "set_default_level", "span",
     "TRACER", "Tracer",
 ]

@@ -5,8 +5,11 @@ Every instrumented entry point (``engine_prune``, ``engine_prune_batch``,
 creates a :func:`recorder` at the top, feeds it host-side facts while
 it runs, and attaches ``rec.finish()`` — an :class:`ExecReport` — to
 its result. The recorder mirrors everything into the process-wide
-``repro.obs.metrics.REGISTRY`` under ``f"{entry}.{name}"`` keys and, in
-``"trace"`` mode, opens spans on ``repro.obs.trace.TRACER``.
+``repro.obs.metrics.REGISTRY`` under ``f"{entry}.{name}"`` keys. Its
+spans (``rec.span("pass1")`` -> ``engine_prune.pass1``) are profiler
+annotations at every level but ``"off"``; in ``"trace"`` mode they also
+record wall-clock events on ``repro.obs.trace.TRACER``. Code with no
+recorder opens the same spans with :func:`span`.
 
 The no-instruments-inside-jit rule, concretely:
 
@@ -47,6 +50,22 @@ def set_default_level(level: str) -> None:
             f"obs level must be one of {OBS_MODES}, got {level!r}")
     global _default_level
     _default_level = level
+
+
+_OFF = contextlib.nullcontext()
+
+
+def span(name: str, level: str | None = None):
+    """A named host span for code that has no recorder (the suite's
+    query bodies, query completion): a profiler annotation, plus a
+    wall-clock event on ``TRACER`` at ``"trace"``; a no-op at ``"off"``.
+    ``level`` defaults to the process-wide level. Never syncs."""
+    lvl = _default_level if level is None else level
+    if lvl == "off":
+        return _OFF
+    if lvl == "trace":
+        return trace.TRACER.span(name)
+    return trace.annotation(name)
 
 
 @dataclasses.dataclass
@@ -125,7 +144,7 @@ class _NullRecorder:
         pass
 
     def span(self, name, **args):
-        return contextlib.nullcontext()
+        return _OFF
 
     def sync(self, x):
         return x
@@ -174,9 +193,11 @@ class Recorder:
         self.meta.update(meta)
 
     def span(self, name: str, **args):
-        """Trace-mode nested span; a no-op context otherwise."""
+        """The span ``<entry>.<name>``: a profiler annotation; in trace
+        mode also a wall-clock event (with ``args``) on the tracer and
+        in this call's report."""
         if self.level != "trace":
-            return contextlib.nullcontext()
+            return trace.annotation(f"{self.entry}.{name}")
         return self.tracer.span(f"{self.entry}.{name}", args=args or None,
                                 sink=self.spans)
 

@@ -1,11 +1,19 @@
-"""Nestable wall-clock spans + Chrome trace-event export.
+"""Spans on the profiler's clock + Chrome trace-event export.
 
-``Tracer.span("pass1")`` times a region with ``time.perf_counter`` and
-records one complete ("X") Chrome trace event — the JSON format both
-Perfetto (https://ui.perfetto.dev) and ``chrome://tracing`` load
-directly. Spans nest through a thread-local stack, so the exported
-trace shows the engine's phase hierarchy (engine_prune > pass1 /
-gather_merge / pass2_apply) on one track per thread.
+Every span is a ``jax.profiler.TraceAnnotation``: while a profiler
+session runs (``jax.profiler.trace(dir)``) it lands in the same trace
+as the device's operations, on the host thread that opened it, so a
+device-idle gap can be read against what the host was doing; with no
+session it costs under a microsecond. ``annotation(name)`` is that
+primitive alone.
+
+``Tracer.span("pass1")`` opens the same annotation and also times the
+region with ``time.perf_counter``, recording one complete ("X") Chrome
+trace event — the JSON format both Perfetto (https://ui.perfetto.dev)
+and ``chrome://tracing`` load directly, without a profiler session.
+Spans nest through a thread-local stack, so the exported trace shows
+the engine's phase hierarchy (engine_prune > pass1 / gather_merge /
+pass2_apply) on one track per thread.
 
 Timing is wall-clock around the *host-side* calls; callers that want
 dispatch and compute separated block on the phase's outputs at span
@@ -20,11 +28,17 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
+# a span's profiler half: enter it around a host region
+annotation = TraceAnnotation
+
 
 class _Span:
     """Context manager recording one complete event on exit."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_depth", "_sink")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_depth", "_sink",
+                 "_note")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict | None,
                  sink: list | None):
@@ -37,11 +51,14 @@ class _Span:
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self.name)
+        self._note = annotation(self.name)
+        self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self._note.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] == self.name:
             stack.pop()

@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 from repro import compat, core
 from repro.constants import NEG
 from repro.core.options import ExecOptions
+from repro.obs import span as obs_span
 from .tables import DictColumn, Table
 
 
@@ -293,7 +294,8 @@ def run_query(spec: QuerySpec, tables, mesh=None, axis: str = "data",
     algo, streams, encs, params, complete = _prepare(spec, tables, decode)
     r = _engine_call(algo, streams, mesh, axis, params, tune,
                      plan_cache, encoding=encs, obs=opts.obs)
-    out = complete(r)
+    with obs_span("run_query.master", opts.obs):
+        out = complete(r)
     out["report"] = r.report
     return out
 
@@ -421,7 +423,8 @@ def run_queries(specs, tables, mesh=None, axis: str = "data",
                 emitted=(None if rb.emitted is None else
                          jax.tree_util.tree_map(lambda a: a[j],
                                                 rb.emitted)))
-            results[i] = prepped[j][4](rj)
+            with obs_span("run_query.master", obs):
+                results[i] = prepped[j][4](rj)
             # one batched dispatch served the whole group: every member
             # shares the group's ExecReport
             results[i]["report"] = rb.report

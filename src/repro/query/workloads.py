@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import core
+from repro.obs import span
 from .engine import QuerySpec, run_query
 from .tables import Table
 
@@ -103,17 +104,26 @@ def tpch_tables(scale: int = 30_000, seed: int = 0) -> dict:
 
 
 # ------------------------------------------------------------- Q1 bodies
+# Each body opens ``suite.<q>`` and a span per stage inside it:
+# ``to_host`` (a device->host copy), ``master`` (host completion),
+# ``to_device`` (a host->device upload), and the query's switch stage.
 def _q1_run(tables, tune="off", plan_cache=None):
     li = tables["lineitem"]
-    keep = np.asarray(li.cols["shipdate"]) <= Q1_SHIP_CUT
-    scanned = Table("lineitem_q1", {
-        "flag": jnp.asarray(np.asarray(li.cols["flag"])[keep]),
-        "revenue": jnp.asarray(np.asarray(li.cols["revenue"])[keep]),
-    })
-    r = run_query(QuerySpec("groupby", ("flag", "revenue"),
-                            dict(d=8, w=4)),
-                  scanned, tune=tune, plan_cache=plan_cache)
-    return {int(k): float(v) for k, v in r["output"].items()}
+    with span("suite.q1"):
+        with span("suite.q1.to_host"):
+            shipdate = np.asarray(li.cols["shipdate"])
+            flag = np.asarray(li.cols["flag"])
+            revenue = np.asarray(li.cols["revenue"])
+        with span("suite.q1.master"):
+            keep = shipdate <= Q1_SHIP_CUT
+            flag, revenue = flag[keep], revenue[keep]
+        with span("suite.q1.to_device"):
+            scanned = Table("lineitem_q1", {"flag": jnp.asarray(flag),
+                                            "revenue": jnp.asarray(revenue)})
+        r = run_query(QuerySpec("groupby", ("flag", "revenue"),
+                                dict(d=8, w=4)),
+                      scanned, tune=tune, plan_cache=plan_cache)
+        return {int(k): float(v) for k, v in r["output"].items()}
 
 
 def _q1_reference(tables):
@@ -130,25 +140,41 @@ def _q1_reference(tables):
 # ------------------------------------------------------------- Q3 bodies
 def _q3_run(tables, tune="off", plan_cache=None):
     li, orders = tables["lineitem"], tables["orders"]
-    odate_ok = np.asarray(orders.cols["orderdate"]) < Q3_ORDER_CUT
-    # switch side: Bloom filter of surviving orderkeys, superset-safe
-    ok_keys = jnp.where(jnp.asarray(odate_ok), orders.cols["orderkey"],
-                        jnp.uint32(0xFFFFFFFF))
-    bloom = core.bloom_build(ok_keys, 1 << 16, 3)
-    join_keep = np.asarray(core.bloom_query(bloom, li.cols["orderkey"]))
-    # master side: exact membership check on the forwarded superset
-    li_keys = np.asarray(li.cols["orderkey"])
-    exact = np.zeros(li_keys.shape[0], bool)
-    ok_set = np.asarray(orders.cols["orderkey"])[odate_ok]
-    exact[join_keep] = np.isin(li_keys[join_keep], ok_set)
-    # tunable TOP-N over the joined survivors' extprice
-    vals = jnp.asarray(np.asarray(li.cols["extprice"])[exact])
-    keys = li_keys[exact]
-    r = _engine("topn_det", (vals,), dict(N=Q3_LIMIT, w=8),
-                tune, plan_cache)
-    topv, topi = core.master_complete_topn(vals, r.keep, Q3_LIMIT)
-    return [(int(keys[i]), float(v))
-            for v, i in zip(np.asarray(topv), np.asarray(topi))]
+    with span("suite.q3"):
+        with span("suite.q3.to_host"):
+            odate = np.asarray(orders.cols["orderdate"])
+        with span("suite.q3.master"):
+            odate_ok = odate < Q3_ORDER_CUT
+        # switch side: Bloom filter of surviving orderkeys, superset-safe
+        with span("suite.q3.join"):
+            ok_keys = jnp.where(jnp.asarray(odate_ok),
+                                orders.cols["orderkey"],
+                                jnp.uint32(0xFFFFFFFF))
+            bloom = core.bloom_build(ok_keys, 1 << 16, 3)
+            joined = core.bloom_query(bloom, li.cols["orderkey"])
+        with span("suite.q3.to_host"):
+            join_keep = np.asarray(joined)
+        # master side: exact membership check on the forwarded superset
+        with span("suite.q3.to_host"):
+            li_keys = np.asarray(li.cols["orderkey"])
+        with span("suite.q3.to_host"):
+            o_keys = np.asarray(orders.cols["orderkey"])
+        with span("suite.q3.master"):
+            exact = np.zeros(li_keys.shape[0], bool)
+            exact[join_keep] = np.isin(li_keys[join_keep], o_keys[odate_ok])
+        # tunable TOP-N over the joined survivors' extprice
+        with span("suite.q3.to_host"):
+            extprice = np.asarray(li.cols["extprice"])
+        with span("suite.q3.master"):
+            vals, keys = extprice[exact], li_keys[exact]
+        with span("suite.q3.to_device"):
+            vals = jnp.asarray(vals)
+        r = _engine("topn_det", (vals,), dict(N=Q3_LIMIT, w=8),
+                    tune, plan_cache)
+        with span("suite.q3.master"):
+            topv, topi = core.master_complete_topn(vals, r.keep, Q3_LIMIT)
+            return [(int(keys[i]), float(v))
+                    for v, i in zip(np.asarray(topv), np.asarray(topi))]
 
 
 def _q3_reference(tables):
@@ -179,12 +205,19 @@ def _q6_run(tables, tune="off", plan_cache=None):
     # knob is accepted (uniform suite API) and ignored
     li = tables["lineitem"]
     cols = {c: li.cols[c] for c in ("shipdate", "discount", "quantity")}
-    pr = core.filter_prune(_Q6_FORMULA, cols)
-    final = np.asarray(core.master_complete_filter(_Q6_FORMULA, cols,
-                                                   pr.keep))
-    rev = np.asarray(li.cols["revenue"]).astype(np.int64)
-    disc = np.asarray(li.cols["discount"]).astype(np.int64)
-    return int((rev[final] * disc[final]).sum())
+    with span("suite.q6"):
+        with span("suite.q6.switch"):
+            pr = core.filter_prune(_Q6_FORMULA, cols)
+            final = core.master_complete_filter(_Q6_FORMULA, cols, pr.keep)
+        with span("suite.q6.to_host"):
+            final = np.asarray(final)
+        with span("suite.q6.to_host"):
+            rev = np.asarray(li.cols["revenue"])
+        with span("suite.q6.to_host"):
+            disc = np.asarray(li.cols["discount"])
+        with span("suite.q6.master"):
+            rev, disc = rev.astype(np.int64), disc.astype(np.int64)
+            return int((rev[final] * disc[final]).sum())
 
 
 def _q6_reference(tables):
