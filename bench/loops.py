@@ -36,17 +36,18 @@ def no_span(name: str):
 
 
 # ------------------------------------------------------------ closed loop
-def closed_loop(system, names, *, seconds=None, count=None, span=no_span):
-    """Run ``names`` in turn for ``seconds`` (or ``count`` queries).
+def closed_loop(system, names, *, seconds=None, until=None, span=no_span):
+    """Run ``names`` in turn for ``seconds``, or until the event ``until``
+    is set; the query in flight then finishes.
 
     Returns ``{"kind": "closed", "start", "end", "queries": [(name,
     start, end)], "answers": [(name, answer)]}``, times on ``clock``.
     """
     queries, answers = [], []
     start = clock()
-    deadline = start + (seconds if seconds is not None else float("inf"))
+    deadline = start + seconds if until is None else float("inf")
     i = 0
-    while (clock() < deadline) if count is None else (i < count):
+    while clock() < deadline and not (until is not None and until.is_set()):
         name = names[i % len(names)]
         a = clock()
         with span(f"query.{name}"):
@@ -61,14 +62,19 @@ def closed_loop(system, names, *, seconds=None, count=None, span=no_span):
 
 
 def closed_loop_cut(system, names, *, lead_s, cut_s, start, stop, span):
-    """One query of the mix, run on a worker thread; this thread traces
-    the slice ``[lead_s, lead_s + cut_s]`` of it, as the span
-    ``bench.window``. For a query too long for the profiler's buffer."""
+    """The mix's queries in turn on a worker thread, from ``lead_s``
+    before a traced slice until the slice has ended; this thread traces
+    the slice ``[lead_s, lead_s + cut_s]``, as the span ``bench.window``,
+    and stops the trace before the query in flight finishes. For queries
+    whose whole run the profiler's buffer cannot hold: a query longer
+    than ``lead_s + cut_s`` is the only one, and the slice is a part of
+    it; shorter ones fill the slice whole, but for its two ends."""
     out, err = {}, []
+    ended = threading.Event()
 
     def work():
         try:
-            out.update(closed_loop(system, names, count=1, span=span))
+            out.update(closed_loop(system, names, until=ended, span=span))
         except BaseException as e:  # re-raised on the calling thread
             err.append(e)
             raise
@@ -81,6 +87,7 @@ def closed_loop_cut(system, names, *, lead_s, cut_s, start, stop, span):
         with span("bench.window"):
             time.sleep(cut_s)
     finally:
+        ended.set()
         stop()
         worker.join()
     if err:

@@ -9,10 +9,20 @@ metrics read.
 * layers: device time of the XLA modules (programs) each layer's
   patterns match (``layers/*.json``), clipped to the window, summed over
   devices.
+* spans: the host spans of the thread that ran the window's queries or
+  folds (the harness's ``query.*``/``stream.*`` spans and the program's
+  own inside them), clipped to the window. That is the window's own
+  thread, unless only a worker thread ran them (a slice traced while a
+  worker runs the mix, ``loops.closed_loop_cut``). A span the thread
+  opened before the trace started is not recorded.
+* gaps: each device's idle intervals inside the window, and for each
+  whether a device program was running over it (a gap between the
+  operations of one program, such as the steps of a ``lax.scan``, is the
+  device's own and not the host's).
 * breakdown: the programs that took most device time (operations, where
   no program began inside the window), and the longest idle gaps, each
-  named by the innermost host span of the benchmark's own thread that
-  covers it.
+  named ``inside <program>`` or by the innermost host span that covers
+  it.
 
 The TPU profiler records every operation and keeps about 6.3M events a
 session (a 9 s Q1 query's one-lane scan emits ~18M, and the trace stops
@@ -28,6 +38,7 @@ import numpy as np
 
 OPS_LINES = ("XLA Ops", "Async XLA Ops")
 MODULES_LINE = "XLA Modules"
+OURS = ("query.", "stream.")   # the harness's spans around each call
 
 
 def _events(line):
@@ -66,11 +77,38 @@ def _short(op: str) -> str:
     return op.split(" = ", 1)[0]
 
 
+def _query_thread(lines, window: str, lo: float, hi: float):
+    """The events of the host thread that ran the window's queries or
+    folds: the one that opened the window, unless only another thread
+    opened the harness's spans inside it."""
+    own = next(ln for ln in lines if window in ln[0])
+
+    def ran(line):
+        n, s, e = line
+        return any(x.startswith(OURS) and a < hi and b > lo
+                   for x, a, b in zip(n, s, e))
+
+    return next((ln for ln in [own, *lines] if ran(ln)), own)
+
+
+def _inside(t, starts, ends) -> np.ndarray:
+    """For each time in ``t``, whether one of the intervals covers it."""
+    if starts.size == 0:
+        return np.zeros(len(t), bool)
+    us, ue = _union(starts, ends)
+    i = np.searchsorted(us, t, side="right") - 1
+    return (i >= 0) & (t <= ue[np.maximum(i, 0)])
+
+
 def reduce_file(path: str, layers: dict, window: str, top: int = 10) -> dict:
+    """The window's busy, layer and breakdown numbers, and its ``spans``
+    (``[(name, start_s, end_s)]``), ``gaps`` (per device, ``[(start_s,
+    end_s)]``) and ``gap_in_program`` (per device, one bool a gap), in
+    seconds from the window's start."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
-    host_spans = None         # the events of the thread that opened window
+    host = None               # the lines of the host plane with the window
     devices = []              # per device: (ops events, modules events)
     for plane in data.planes:
         if plane.name.startswith("/device:") and "TPU" in plane.name:
@@ -84,17 +122,17 @@ def reduce_file(path: str, layers: dict, window: str, top: int = 10) -> dict:
                         if MODULES_LINE in lines else ([], np.zeros(0),
                                                        np.zeros(0)))
                 devices.append((merged, mods))
-        elif plane.name.startswith("/host:") and host_spans is None:
-            for ln in plane.lines:
-                n, s, e = _events(ln)
-                if window in n:
-                    host_spans = list(zip(n, s, e))
-                    break
-    if host_spans is None:
+        elif plane.name.startswith("/host:") and host is None:
+            lines = [_events(ln) for ln in plane.lines]
+            if any(window in n for n, _, _ in lines):
+                host = lines
+    if host is None:
         raise ValueError(f"no host span {window!r} in {path}")
-    lo, hi = next((s, e) for n, s, e in host_spans if n == window)
+    lo, hi = next((s, e) for ln in host for n, s, e in zip(*ln)
+                  if n == window)
+    host_spans = list(zip(*_query_thread(host, window, lo, hi)))
 
-    busy, gaps, by_name, programs = [], [], {}, []
+    busy, gaps, in_prog, by_name, programs = [], [], [], {}, []
     layer_s = dict.fromkeys(layers, 0.0)
     pats = {k: [re.compile(p) for p in v] for k, v in layers.items()}
     for (names, s, e), mods in devices:
@@ -104,9 +142,11 @@ def reduce_file(path: str, layers: dict, window: str, top: int = 10) -> dict:
             continue
         us, ue = _union(cs[inside], ce[inside])
         busy.append(float((ue - us).sum()) * 1e-9)
-        gaps.extend((a, b) for a, b in zip(np.concatenate([[lo], ue]),
-                                           np.concatenate([us, [hi]]))
-                    if b > a)
+        ga, gb = np.concatenate([[lo], ue]), np.concatenate([us, [hi]])
+        keep = gb > ga
+        ga, gb = ga[keep], gb[keep]
+        gaps.append((ga, gb))
+        in_prog.append(_inside((ga + gb) / 2, mods[1], mods[2]))
         programs.extend(zip(*mods))
         mod_names, mod_d = _clipped(mods, lo, hi)
         for name, d in zip(mod_names, mod_d):
@@ -121,18 +161,25 @@ def reduce_file(path: str, layers: dict, window: str, top: int = 10) -> dict:
     if not busy:
         raise ValueError(f"no device operation inside {window!r} in {path}")
 
-    gaps.sort(key=lambda g: g[0] - g[1])
+    longest = sorted(((a, b) for ga, gb in gaps for a, b in zip(ga, gb)),
+                     key=lambda g: g[0] - g[1])[:top]
     return {
         "window_s": (hi - lo) * 1e-9,
         "busy_s": float(np.mean(busy)),
         "devices": len(busy),
         "layer_s": layer_s,
+        "spans": [(n, (max(s, lo) - lo) * 1e-9, (min(e, hi) - lo) * 1e-9)
+                  for n, s, e in host_spans
+                  if n != window and min(e, hi) > max(s, lo)],
+        "gaps": [list(zip(((ga - lo) * 1e-9).tolist(),
+                          ((gb - lo) * 1e-9).tolist())) for ga, gb in gaps],
+        "gap_in_program": [m.tolist() for m in in_prog],
         "breakdown": {
             "device_ops": [[n, t] for n, t in sorted(
                 by_name.items(), key=lambda kv: -kv[1])[:top]],
             "idle_gaps": [[_gap_name(programs, host_spans, (a + b) / 2,
                                      window), (b - a) * 1e-9]
-                          for a, b in gaps[:top]]},
+                          for a, b in longest]},
     }
 
 
@@ -151,7 +198,7 @@ def _host_name(spans, t: float, window: str) -> str:
     cover = [(e - s, n) for n, s, e in spans if s <= t <= e and n != window]
     if not cover:
         return "host: no span"
-    ours = sorted(c for c in cover if c[1].startswith(("query.", "stream.")))
+    ours = sorted(c for c in cover if c[1].startswith(OURS))
     inner = min(cover)[1]
     if ours and ours[0][1] != inner:
         return f"{ours[0][1]} > {inner}"

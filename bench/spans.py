@@ -5,18 +5,19 @@ The program names its spans ``<entry>.<stage>`` (``suite.q3.master``,
 ``engine_prune.to_host``, ``prune_stream.prepare``, ...) on the
 profiler's clock, one of the entries in ``PROGRAM``; the harness's own
 spans start ``bench.``, ``query.`` or ``stream.``, and JAX's runtime
-adds its own (``PjitFunction(...)``, ``DevicePut``, ...). ``read``
-returns every span of the thread that opened the window, clipped to
-it, and each device's idle intervals in it. The readers below select
-these stages of the program's spans:
+adds its own (``PjitFunction(...)``, ``DevicePut``, ...).
+``reduce_trace.reduce_file`` returns the spans of the thread that ran
+the window's queries or folds, clipped to the window, and each device's
+idle intervals in it; ``read`` returns that reduction. The readers below
+select these stages of the program's spans:
 
 * ``to_host``: a device->host copy the master needs;
 * ``master``: host completion;
 * ``to_device``: a host->device upload on the query path.
 
 Each reader returns None where the trace holds none of what it reads
-(a program that opens no spans). ``reduce_trace.reduce_file`` returns
-neither spans nor gaps, so no ``metrics/*.py`` can read these yet.
+(a program that opens no spans). The harness's ``metrics/*.py`` call
+these readers on its own reduction of each traced run.
 
     python3 bench/spans.py read <trace.xplane.pb>
     python3 bench/spans.py record --workload <cell> --seed <n>
@@ -25,9 +26,11 @@ neither spans nor gaps, so no ``metrics/*.py`` can read these yet.
 
 ``record`` runs the cell's traced window through the harness (on a
 chip; ``--scale`` as the tests scale a configuration, ``--set`` to
-shorten the mix for a small trace, e.g. ``trace_seconds=0.05``),
-prints the harness's per-layer metrics and breakdown with these
-readings, and keeps the trace at ``--out``.
+shorten the mix for a small trace, e.g. ``trace_seconds=0.05``, or
+``trace_lead_s=0.3 --set trace_cut_s=0.1`` for a sliced closed loop),
+prints the harness's per-layer metrics (the span metrics among them, as
+its ``metrics/*.py`` read them) and breakdown beside these readings,
+and keeps the trace at ``--out``.
 """
 from __future__ import annotations
 
@@ -51,43 +54,10 @@ WINDOW = "bench.window"
 
 
 def read(path: str, window: str = WINDOW) -> dict:
-    """``{"window_s", "spans": [(name, start_s, end_s)], "gaps": [[(start_s,
-    end_s)] per device]}``, times from the window's start."""
-    from jax.profiler import ProfileData
-
-    data = ProfileData.from_file(path)
-    host, ops = None, []
-    for plane in data.planes:
-        if plane.name.startswith("/device:") and "TPU" in plane.name:
-            lines = [reduce_trace._events(ln) for ln in plane.lines
-                     if ln.name in reduce_trace.OPS_LINES]
-            if lines:
-                ops.append((np.concatenate([s for _, s, _ in lines]),
-                            np.concatenate([e for _, _, e in lines])))
-        elif plane.name.startswith("/host:") and host is None:
-            for ln in plane.lines:
-                names, s, e = reduce_trace._events(ln)
-                if window in names:
-                    host = list(zip(names, s, e))
-                    break
-    if host is None:
-        raise ValueError(f"no host span {window!r} in {path}")
-    lo, hi = next((s, e) for n, s, e in host if n == window)
-    spans = [(n, (max(s, lo) - lo) * 1e-9, (min(e, hi) - lo) * 1e-9)
-             for n, s, e in host if n != window and min(e, hi) > max(s, lo)]
-    gaps = []
-    for s, e in ops:
-        cs, ce = np.clip(s, lo, hi), np.clip(e, lo, hi)
-        inside = ce > cs
-        if not inside.any():
-            continue
-        us, ue = reduce_trace._union(cs[inside], ce[inside])
-        gaps.append([((a - lo) * 1e-9, (b - lo) * 1e-9) for a, b in
-                     zip(np.concatenate([[lo], ue]),
-                         np.concatenate([us, [hi]])) if b > a])
-    if not gaps:
-        raise ValueError(f"no device operation inside {window!r} in {path}")
-    return {"window_s": (hi - lo) * 1e-9, "spans": spans, "gaps": gaps}
+    """The trace's reduction: ``{"window_s", "spans": [(name, start_s,
+    end_s)], "gaps": [[(start_s, end_s)] per device], "gap_in_program",
+    ...}``, times from the window's start."""
+    return reduce_trace.reduce_file(path, {}, window)
 
 
 def program(name: str) -> bool:
@@ -123,14 +93,16 @@ def _overlap_s(a, b) -> float:
 
 def host_bound_idle_share(t: dict) -> float | None:
     """Share of the window, in percent, in which no operation runs on
-    the device and the host is inside a program span (mean over
-    devices). The rest of the idle time is the harness's own: arrival
-    waits, the loop between queries."""
+    the device, no device program is running either, and the host is
+    inside a program span (mean over devices). The rest of the idle time
+    is the device's own (the gaps between one program's operations) or
+    the harness's: arrival waits, the loop between queries."""
     busy_host = _merged((s, e) for n, s, e in t["spans"] if program(n))
     if not busy_host:
         return None
-    shares = [_overlap_s(busy_host, _merged(g)) / t["window_s"]
-              for g in t["gaps"]]
+    shares = [_overlap_s(busy_host, [g for g, inside in zip(gs, ins)
+                                     if not inside]) / t["window_s"]
+              for gs, ins in zip(t["gaps"], t["gap_in_program"])]
     return 100.0 * float(np.mean(shares))
 
 
@@ -192,8 +164,9 @@ def _record(args) -> dict:
                             recursive=True)
         if args.out:
             shutil.copy(path, args.out)
-        got.update(readings(read(path, window)))
-        return reduce_dir(trace_dir, layers, window)
+        reduced = reduce_dir(trace_dir, layers, window)
+        got.update(readings(reduced))
+        return reduced
 
     def parts(spec, workload):
         cell, centry, cfg, traffic = cell_parts(spec, workload)

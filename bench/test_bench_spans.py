@@ -1,10 +1,12 @@
-"""The span readers (``spans.py``) and the stream's fold layer metric,
-on two traces recorded on a TPU v5e by the harness after the program
-named its stages and stream programs (``spans.py record``:
-``tpch_sf1.q3_q6`` at 0.5% of its rows for 0.025 s, one Q3;
-``tpch_sf1.stream_topn_distinct`` for one micro-batch of
-``STREAM_BATCH`` rows), and on the older q3_q6 trace, whose program
-opened no spans."""
+"""The span readers (``spans.py``), the harness's span metrics and the
+stream's fold layer metric, on three traces recorded on a TPU v5e by the
+harness after the program named its stages and stream programs
+(``spans.py record``: ``tpch_sf1.q3_q6`` at 0.5% of its rows for
+0.025 s, one Q3; ``tpch_sf1.stream_topn_distinct`` for one micro-batch
+of ``STREAM_BATCH`` rows; ``tpch_sf01.q1`` at 0.03% of its rows,
+``--set trace_lead_s=0.3 --set trace_cut_s=0.03``, a slice holding two
+whole Q1s run by the worker thread), on the older q3_q6 trace, whose
+program opened no spans, and on intervals whose answer is known."""
 from __future__ import annotations
 
 import os
@@ -19,6 +21,7 @@ OLD = os.path.join(DATA, "q3_q6_small.xplane.pb")
 Q3 = os.path.join(DATA, "q3_q6_spans_small.xplane.pb")
 STREAM = os.path.join(DATA, "stream_small.xplane.pb")
 STREAM_BATCH = 2048       # the micro-batch it was recorded with
+Q1 = os.path.join(DATA, "q1_small.xplane.pb")
 SPEC = harness.load_spec()
 
 
@@ -38,6 +41,8 @@ def _ctx(path, cell, **sets):
         window = {"kind": "closed",
                   "queries": [(n, s, e) for n, s, e in t["spans"]
                               if n.startswith("query.")]}
+        if "trace_cut_s" in traffic:
+            window["cut_s"] = traffic["trace_cut_s"]
     else:
         folds = sum(n == "stream.fold" for n, _, _ in t["spans"])
         window = {"kind": "open", "offered": folds * traffic["micro_batch"]}
@@ -62,6 +67,48 @@ def test_interval_helpers_match_a_grid():
     ma, mb = spans._merged(a), spans._merged(b)
     assert sum(e - s for s, e in ma) == ga.sum()
     assert spans._overlap_s(ma, mb) == (ga & gb).sum()
+
+
+def test_inside_matches_a_scan_of_the_intervals():
+    rng = np.random.default_rng(5)
+    starts = rng.integers(0, 900, 40).astype(float)
+    ends = starts + rng.integers(0, 60, 40)
+    t = np.arange(-5.0, 1000.0, 0.5)
+    want = [any(s <= x <= e for s, e in zip(starts, ends)) for x in t]
+    assert reduce_trace._inside(t, starts, ends).tolist() == want
+    assert not reduce_trace._inside(t, np.zeros(0), np.zeros(0)).any()
+
+
+def _line(*spans_):
+    return ([n for n, _, _ in spans_], np.array([s for _, s, _ in spans_]),
+            np.array([e for _, _, e in spans_]))
+
+
+def test_spans_come_from_the_thread_that_ran_the_queries():
+    window = _line(("bench.window", 10.0, 20.0))
+    worker = _line(("query.q1_pricing", 5.0, 12.0), ("suite.q1", 5.0, 12.0),
+                   ("query.q1_pricing", 12.0, 15.0), ("suite.q1", 12.0, 15.0))
+    other = _line(("PjitFunction(x)", 11.0, 11.5))
+    pick = reduce_trace._query_thread
+    assert pick([other, window, worker], "bench.window", 10.0, 20.0) is worker
+    # the window's own thread, where it ran them itself
+    both = _line(("bench.window", 10.0, 20.0), ("query.q3", 11.0, 12.0))
+    assert pick([worker, both], "bench.window", 10.0, 20.0) is both
+    # no thread ran one inside the window: the window's own
+    assert pick([other, window, worker], "bench.window", 30.0, 40.0) \
+        is window
+
+
+def test_host_bound_leaves_out_gaps_inside_a_running_program():
+    t = {"window_s": 1.0, "spans": [("suite.q1", 0.0, 0.8),
+                                    ("query.q1_pricing", 0.0, 1.0)],
+         "gaps": [[(0.1, 0.2), (0.5, 0.6), (0.85, 0.9)]],
+         "gap_in_program": [[True, False, False]]}
+    assert spans.host_bound_idle_share(t) == pytest.approx(10.0)
+    t["gap_in_program"] = [[True, True, False]]
+    assert spans.host_bound_idle_share(t) == 0.0
+    t["spans"] = [("query.q1_pricing", 0.0, 1.0)]   # no program span
+    assert spans.host_bound_idle_share(t) is None
 
 
 def test_old_keys_read_as_before_with_the_new_layers():
@@ -139,3 +186,44 @@ def test_stream_readers_positive_and_programs_named():
     assert not any(n.startswith("jit_worker") for n in names)
     for algo in ("distinct", "topn_det"):
         assert any(n.startswith(f"jit_{algo}_stream_fold") for n in names)
+
+
+@pytest.mark.parametrize("path,cell,metric,key", [
+    (Q3, "tpch_sf1.q3_q6", "host_bound_idle_share.query",
+     "host_bound_idle_share"),
+    (Q3, "tpch_sf1.q3_q6", "master_ms_per_query", "master_ms_per_query"),
+    (Q3, "tpch_sf1.q3_q6", "to_host_ms_per_query", "to_host_ms_per_query"),
+    (STREAM, "tpch_sf1.stream_topn_distinct", "host_bound_idle_share.stream",
+     "host_bound_idle_share"),
+], ids=["q3_q6-host_bound", "q3_q6-master", "q3_q6-to_host",
+        "stream-host_bound"])
+def test_harness_span_metrics_equal_spans_readings(path, cell, metric, key):
+    entry = next(m for m in SPEC["per_layer"] if m["name"] == metric)
+    assert cell in entry["workloads"]
+    want = spans.readings(spans.read(path))[key]
+    assert want is not None
+    assert _metric(metric, _ctx(path, cell)) == want
+
+
+def test_q1_slice_holds_whole_queries_of_the_worker_thread():
+    t = spans.read(Q1)
+    whole = [(s, e) for n, s, e in t["spans"]
+             if n == "query.q1_pricing" and 0 < s and e < t["window_s"]]
+    assert len(whole) >= 2
+    # their stages come from the worker thread, inside the slice
+    names = {n for n, _, _ in t["spans"]}
+    assert {"suite.q1", "suite.q1.master", "suite.q1.to_host"} <= names
+    # the scan's gaps between steps run inside its program and are not
+    # host-bound; the reader counts only the rest
+    gaps, inside = t["gaps"][0], t["gap_in_program"][0]
+    assert any(inside) and not all(inside)
+    outside = sum(b - a for (a, b), i in zip(gaps, inside) if not i)
+    share = spans.host_bound_idle_share(t)
+    assert 0 < share <= 100 * outside / t["window_s"] * (1 + 1e-9)
+    ctx = _ctx(Q1, "tpch_sf01.q1")
+    assert _metric("host_bound_idle_share.query", ctx) == share
+    assert _metric("pass1_share.query", ctx) > 0
+    assert 0 < _metric("device_idle_share.query", ctx) < 100
+    for stage in ("master_ms_per_query", "to_host_ms_per_query",
+                  "pass1_ms_per_query"):    # a slice's queries are not whole
+        assert _metric(stage, ctx) is None
