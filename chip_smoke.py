@@ -17,9 +17,7 @@ stderr and exits 2.
                  ``SuiteQuery.run``, each == its plain-Python reference
   C  batched     16 TOP-N specs with mixed N over lineitem extprice via
                  ``run_queries`` == serial ``run_query`` == exact top-N,
-                 on the analytic two-pass plan (``tune="cached"`` with no
-                 stored plans): the default meshless scan walks the 6M
-                 entries one step at a time, about 16 us a step on a v5e
+                 on the meshless default (two-pass on sqrt(m) lanes)
   D  stream      2^22 extprice / orderkey entries through ``PruneStream``
                  (TOP-N, DISTINCT) in 2^14-entry micro-batches;
                  ``close().keep`` == one-shot two_pass on ``lane_view``
@@ -141,14 +139,6 @@ def phase_tpch(log, tables) -> None:
              warm_t)
 
 
-class NoStoredPlans:
-    """A plan cache that holds no plan and reads no file: with
-    ``tune="cached"`` every query runs its analytic plan."""
-
-    def get(self, key):
-        return None
-
-
 def phase_batched(log, tables, ns=BATCH_NS) -> None:
     import numpy as np
 
@@ -157,13 +147,12 @@ def phase_batched(log, tables, ns=BATCH_NS) -> None:
     li = tables["lineitem"]
     specs = [QuerySpec("topn", ("extprice",), dict(mode="det", N=n, w=8))
              for n in ns]
-    plans = dict(tune="cached", plan_cache=NoStoredPlans())
-    a, b, cold, warm = cold_warm(lambda: run_queries(specs, li, **plans))
+    a, b, cold, warm = cold_warm(lambda: run_queries(specs, li))
     exact_v, exact_i = top_n_reference(np.asarray(li.cols["extprice"]),
                                        max(ns))
     for spec, ga, gb in zip(specs, a, b):
         n = spec.params["N"]
-        serial = run_query(spec, li, **plans)
+        serial = run_query(spec, li)
         for g in (ga, gb):
             check(all(np.array_equal(np.asarray(x), np.asarray(y))
                       for x, y in zip(g["output"], serial["output"])),

@@ -37,7 +37,7 @@ from .planner import (SwitchProfile, ResourceFootprint, footprint,
                       RESIDENT_OVERHEAD_ENTRIES, optimal_merge_interval,
                       DEFAULT_STALENESS_RATE, Plan, TuneResult,
                       TUNE_MODES, analytic_plan, candidate_plans, tune,
-                      resolve_plan)
+                      resolve_plan, meshless_lanes, meshless_plan)
 from .encoding import (DictEncoding, dict_encode, normalize_encodings,
                        rle_encode, rle_expand)
 from .options import DECODE_MODES, ExecOptions

@@ -203,6 +203,10 @@ class _AlgoSpec:
     # the streams (GROUP BY: COUNT folds +1 per entry, so no pad *value*
     # is neutral — only a valid=False flag is).
     pad_validity: bool = False
+    # True when pass 1 is one vectorised build over the whole stream,
+    # not a sequential scan (HAVING's Count-Min sketch): more lanes only
+    # copy its state, so the meshless default runs it on one lane.
+    vector_pass1: bool = False
 
 
 def _cols_by_shard(stacked: jnp.ndarray) -> jnp.ndarray:
@@ -476,7 +480,7 @@ _SPECS: dict[str, _AlgoSpec] = {
     "having": _AlgoSpec(_having_scan, _having_pads,
                         _having_merge, _having_apply,
                         resume=_having_resume, init=_having_init,
-                        sharded_needs_merge=True),
+                        sharded_needs_merge=True, vector_pass1=True),
 }
 
 

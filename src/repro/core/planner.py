@@ -550,6 +550,58 @@ def analytic_plan(algo: str, streams, params: dict | None = None, *,
                 num_devices=ndev if mode == "mesh" else 1)
 
 
+# Lane cap of the meshless default for the chunkable algorithms
+# (DISTINCT, SKYLINE): their pass 2 compares each of the m entries with
+# all S·w merged columns, work that grows with S while pass 1 shrinks.
+CHUNKABLE_MAX_LANES = 256
+# Elements of one chunk of that compare, [S, block, S·w(, D)].
+APPLY_CHUNK_ELEMENTS = 1 << 26
+
+
+def meshless_lanes(algo: str, m: int) -> int:
+    """Pass-1 lanes of the meshless ``tune="off"`` query default.
+
+    ceil(sqrt(m)) lanes of about sqrt(m) steps each, capped at
+    ``CHUNKABLE_MAX_LANES`` for the chunkable algorithms and clamped to
+    [1, m]; one lane (the scan) where pass 1 is a vectorised build
+    rather than a sequential scan. A function of m and the algorithm
+    alone: no timing and no query parameter, so a batched group and its
+    serial loop share S.
+    """
+    from . import engine as _engine  # lazy: engine imports planner
+
+    if _engine._SPECS[algo].vector_pass1:
+        return 1
+    s = math.isqrt(m - 1) + 1 if m > 1 else 1
+    if _engine._SPECS[algo].chunkable:
+        s = min(s, CHUNKABLE_MAX_LANES)
+    return max(1, min(s, m))
+
+
+def meshless_plan(algo: str, streams, queries) -> Plan:
+    """The meshless ``tune="off"`` plan of ``query.run_query`` and of
+    each batched ``query.run_queries`` group: two-pass on
+    ``meshless_lanes`` lanes. For a chunkable algorithm the pass-2
+    apply runs in blocks sized from the shapes, so one block's
+    [S, block, S·w(, D)] compare stays within ``APPLY_CHUNK_ELEMENTS``,
+    w being the widest of ``queries``. The block changes speed, never
+    the mask. ``analytic_plan`` keeps the engine's fixed
+    ``DEFAULT_MESH_APPLY_BLOCK``, the block its cached and raced plans
+    were measured with; here the compare grows with S² up to
+    ``CHUNKABLE_MAX_LANES`` lanes, so the block shrinks as S grows.
+    """
+    from . import engine as _engine
+
+    streams = tuple(s for s in streams if s is not None)
+    s = meshless_lanes(algo, int(streams[0].shape[0]))
+    block = None
+    if _engine._SPECS[algo].chunkable:
+        w = max(int(q["w"]) for q in queries)
+        per_entry = s * s * w * math.prod(streams[0].shape[1:])
+        block = max(1, APPLY_CHUNK_ELEMENTS // per_entry)
+    return Plan(mode="two_pass", shards=s, apply_block=block)
+
+
 def candidate_plans(algo: str, streams, params: dict | None = None, *,
                     incumbent: Plan | None = None,
                     max_devices: int | None = None,

@@ -10,10 +10,15 @@ Deployment mapping (DESIGN.md §2):
    worker; shard-local states all-gathered *across the workers*, the
    merged state broadcast back, and the pass-2 filter applied to each
    worker's resident entries — the master never re-touches the entry
-   stream), ``mode="scan"`` otherwise. The engine hands back a
-   device-sharded stacked keep mask; this module flattens only the
-   mask (O(m) bools via ``core.unshard_mask``) for master completion
-   over the worker-resident columns it already holds. The engine is
+   stream). Without a mesh, ``tune="off"`` (the default) runs
+   ``mode="two_pass"`` on ``core.meshless_lanes`` lanes, about sqrt(m)
+   of them, one (the scan) for HAVING's vectorised sketch build
+   (``core.meshless_plan``); ``tune="cached"``/``"race"`` run
+   a tuned plan. On a
+   mesh the engine hands back a device-sharded stacked keep mask; this
+   module flattens only the mask (O(m) bools via
+   ``core.unshard_mask``) for master completion over the
+   worker-resident columns it already holds. The engine is
    the single entry point for scan / sharded / two_pass / mesh
    execution; this module only adds table plumbing and master
    completion.
@@ -73,25 +78,24 @@ def _engine_call(algo: str, streams: tuple, mesh, axis: str,
                  obs: str | None = None) -> core.PruneResult:
     """One engine invocation per query: mesh-backed when a mesh exists
     (S = one lane per worker on the data axis, pass 2 resident on the
-    workers), sequential otherwise. The result's keep mask is
-    normalized to the flat bool[m] layout — only the mask is gathered
-    (``unshard_mask``); the entry stream stays sharded on the workers
-    and master completion reads the columns this layer already holds.
+    workers), two-pass on ``core.meshless_plan``'s S ~ sqrt(m) lanes
+    otherwise. The result's keep mask is normalized to the flat
+    bool[m] layout — only the mask is gathered (``unshard_mask``); the
+    entry stream stays sharded on the workers and master completion
+    reads the columns this layer already holds.
 
-    tune != "off" (meshless only) replaces the scan fallback with a
+    tune != "off" (meshless only) replaces that default with a
     cached/raced two-pass-family plan (see ``core.planner.tune``); the
     mask stays flat and bit-identical to the analytic plan's.
 
     encoding: per-stream ``DictEncoding | None`` tuple — streams carry
     codes and pass 1 prunes in code space (see ``core.engine``).
     """
-    if tune != "off":
-        tr = core.resolve_plan(algo, streams, params, tune_mode=tune,
-                               cache=plan_cache, obs=obs)
-        return core.execute_plan(algo, *streams, plan=tr.plan,
-                                 encoding=encoding, obs=obs, **params)
     if mesh is None:
-        return core.engine_prune(algo, *streams, mode="scan",
+        plan = (core.meshless_plan(algo, streams, [params]) if tune == "off"
+                else core.resolve_plan(algo, streams, params, tune_mode=tune,
+                                       cache=plan_cache, obs=obs).plan)
+        return core.execute_plan(algo, *streams, plan=plan,
                                  encoding=encoding, obs=obs, **params)
     r = core.engine_prune(algo, *streams, mode="mesh",
                           shards=mesh.shape[axis], mesh=mesh,
@@ -334,9 +338,11 @@ def run_queries(specs, tables, mesh=None, axis: str = "data",
 
     Specs are grouped by `_group_key` (same algorithm family, columns
     and family statics); each multi-spec group runs through
-    ``core.engine_prune_batch`` — one scan of the shared stream, and on
-    a mesh one `shard_map` dispatch + one fused state collective for
-    the whole group, with pass 2 resident on the workers.  Singleton
+    ``core.engine_prune_batch`` — one pass 1 over the shared stream:
+    without a mesh (``tune="off"``) two-pass on the same
+    ``core.meshless_lanes`` lanes a serial `run_query` uses, on a mesh
+    one `shard_map` dispatch + one fused state collective for the
+    whole group, with pass 2 resident on the workers.  Singleton
     groups and join/filter specs fall back to `run_query`.  Results
     come back in input order, one `run_query`-shaped dict per spec,
     bit-identical to a serial `run_query` loop.
@@ -383,17 +389,14 @@ def run_queries(specs, tables, mesh=None, axis: str = "data",
         algo, streams, encs = prepped[0][0], prepped[0][1], prepped[0][2]
         queries = [pr[3] for pr in prepped]
         m = streams[0].shape[0]
-        if tune != "off":
-            tr = core.resolve_plan(algo, streams, queries[0],
-                                   tune_mode=tune, cache=plan_cache,
-                                   obs=obs)
+        if mesh is None:
+            plan = (core.meshless_plan(algo, streams, queries)
+                    if tune == "off" else
+                    core.resolve_plan(algo, streams, queries[0],
+                                      tune_mode=tune, cache=plan_cache,
+                                      obs=obs).plan)
             rb = core.execute_plan_batch(
-                algo, queries, *streams, plan=tr.plan, encoding=encs,
-                device_budget_bytes=device_budget_bytes, obs=obs)
-            keep = rb.keep
-        elif mesh is None:
-            rb = core.engine_prune_batch(
-                algo, queries, *streams, mode="scan", encoding=encs,
+                algo, queries, *streams, plan=plan, encoding=encs,
                 device_budget_bytes=device_budget_bytes, obs=obs)
             keep = rb.keep
         else:
@@ -411,8 +414,7 @@ def run_queries(specs, tables, mesh=None, axis: str = "data",
                 # trim batch-cap pads (always-invalid slots) back to the
                 # query's own (d, w) so master completion and traffic
                 # stats see the serial state shape; columns come in
-                # per-shard blocks of the batch w-cap (one block in
-                # scan mode)
+                # per-shard blocks of the batch w-cap
                 d, w = int(queries[j]["d"]), int(queries[j]["w"])
                 state_j = jax.tree_util.tree_map(
                     lambda a: a.reshape(a.shape[0], -1, w_cap)

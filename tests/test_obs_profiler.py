@@ -121,7 +121,9 @@ def test_run_query_spans(tmp_path, obs_level):
             return run_query(spec, uv, obs=obs_level)
 
     _, spans = _profiled(tmp_path, call)
-    for name in ("engine_prune.scan", "engine_prune.to_host",
+    # the meshless default runs two-pass: pass 1, merge and apply
+    for name in ("engine_prune.pass1", "engine_prune.gather_merge",
+                 "engine_prune.pass2_apply", "engine_prune.to_host",
                  "run_query.master"):
         assert _inside(spans, name, "query.topn")
     # completion starts after the engine's mask read-back has ended

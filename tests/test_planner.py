@@ -164,3 +164,42 @@ def test_plan_query_batch_hashable_static_metadata():
     a = plan_query_batch([10, 20], device_budget_bytes=25)
     b = plan_query_batch([10, 20], device_budget_bytes=25)
     assert a == b and hash(a) == hash(b)
+
+
+@pytest.mark.parametrize("m,groupby,distinct", [
+    (1, 1, 1), (2, 2, 2), (4, 2, 2), (5, 3, 3), (591_788, 770, 256),
+    (6_001_215, 2450, 256)])
+def test_meshless_lanes_rule(m, groupby, distinct):
+    """ceil(sqrt(m)) lanes clamped to [1, m]; the chunkable algorithms
+    stop at CHUNKABLE_MAX_LANES; HAVING, whose pass 1 is one vectorised
+    sketch build, keeps one lane."""
+    from repro.core import meshless_lanes
+
+    assert meshless_lanes("groupby", m) == groupby
+    assert meshless_lanes("topn_det", m) == groupby
+    assert meshless_lanes("topn_rand", m) == groupby
+    assert meshless_lanes("having", m) == 1
+    assert meshless_lanes("distinct", m) == distinct
+    assert meshless_lanes("skyline", m) == distinct
+
+
+@pytest.mark.parametrize("algo,shape,w", [
+    ("distinct", (6_001_215,), 4), ("skyline", (6_001_215, 2), 64),
+    ("groupby", (6_001_215,), 4)])
+def test_meshless_plan_bounds_the_apply_chunk_at_sf1(algo, shape, w):
+    """At SF1 lineitem's length one block of the chunkable applies stays
+    within APPLY_CHUNK_ELEMENTS; a non-chunkable plan has no block."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import meshless_plan, planner
+
+    stream = jax.ShapeDtypeStruct(shape, jnp.float32)
+    plan = meshless_plan(algo, (stream,), [dict(w=w), dict(w=1)])
+    assert plan.mode == "two_pass"
+    assert plan.shards == planner.meshless_lanes(algo, shape[0])
+    if algo == "groupby":
+        assert plan.apply_block is None
+        return
+    per_entry = plan.shards * plan.shards * w * math.prod(shape[1:])
+    assert plan.apply_block * per_entry <= planner.APPLY_CHUNK_ELEMENTS
+    assert plan.apply_block < -(-shape[0] // plan.shards)

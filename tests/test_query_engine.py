@@ -12,8 +12,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro import core
-from repro.query import (QuerySpec, make_products_ratings, make_rankings,
-                         make_uservisits, run_query)
+from repro.query import (QuerySpec, Table, make_products_ratings,
+                         make_rankings, make_uservisits, run_query)
 
 
 def test_running_example_products_ratings():
@@ -142,7 +142,7 @@ def _multiq_specs():
 
 def test_run_queries_matches_serial_loop():
     """Mixed specs grouped into batches come back in input order with
-    results identical to a per-spec run_query loop (scan path)."""
+    results identical to a per-spec run_query loop (meshless default)."""
     from repro.query import run_queries
 
     uv = make_uservisits(8000, seed=11)
@@ -223,3 +223,153 @@ def test_readme_mesh_example_runs():
     want = np.sort(np.asarray(uv.cols["ad_revenue"]))[-100:]
     assert np.array_equal(np.sort(np.asarray(res["output"][0])), want)
     assert 0 < res["pruned_fraction"] < 1
+
+
+# ------------------------------------------------ the meshless default
+_M = 5000  # ceil(sqrt(5000)) = 71 lanes, which do not divide 5000
+
+
+def _algo_table():
+    rs = np.random.default_rng(21)
+    return Table("t", {
+        "k": jnp.asarray(rs.integers(0, 300, _M).astype(np.uint32)),
+        "n": jnp.asarray(rs.integers(1, 50, _M).astype(np.int32)),
+        "v": jnp.asarray((rs.permutation(_M) + 1).astype(np.float32)),
+        "a": jnp.asarray(rs.random(_M).astype(np.float32)),
+        "b": jnp.asarray(rs.random(_M).astype(np.float32)),
+    })
+
+
+# two specs a kind, so run_queries batches them into one group
+_ALGO_SPECS = {
+    "distinct": [QuerySpec("distinct", ("k",), dict(d=64, w=4)),
+                 QuerySpec("distinct", ("k",), dict(d=32, w=2))],
+    "topn_det": [QuerySpec("topn", ("v",), dict(mode="det", N=50, w=4)),
+                 QuerySpec("topn", ("v",), dict(mode="det", N=20, w=6))],
+    "topn_rand": [QuerySpec("topn", ("v",), dict(d=256, w=8, N=20)),
+                  QuerySpec("topn", ("v",), dict(d=128, w=8, N=10))],
+    "skyline": [QuerySpec("skyline", ("a", "b"), dict(w=8)),
+                QuerySpec("skyline", ("a", "b"), dict(w=4))],
+    "groupby": [QuerySpec("groupby", ("k", "n"), dict(d=16, w=4)),
+                QuerySpec("groupby", ("k", "n"), dict(d=8, w=2))],
+    "having": [QuerySpec("having", ("k", "n"),
+                         dict(threshold=500.0, rows=3, width=256)),
+               QuerySpec("having", ("k", "n"),
+                         dict(threshold=700.0, rows=2, width=128))],
+}
+
+
+def _oracle(spec, t):
+    c, p = t.cols, spec.params
+    if spec.kind == "distinct":
+        return set(np.unique(np.asarray(c["k"])).tolist())
+    if spec.kind == "topn":
+        return np.sort(np.asarray(c["v"]))[-p["N"]:]
+    if spec.kind == "skyline":
+        return np.asarray(core.skyline_oracle(
+            jnp.stack([c["a"], c["b"]], axis=-1)))
+    if spec.kind == "groupby":
+        return core.groupby_oracle(c["k"], c["n"], "sum")
+    return core.having_oracle(c["k"], c["n"], p["threshold"])
+
+
+def _answer(spec, out):
+    if spec.kind == "distinct":
+        return set(np.asarray(out).tolist())
+    if spec.kind == "topn":
+        return np.sort(np.asarray(out[0]))
+    if spec.kind == "having":
+        return sorted(out)
+    return out if spec.kind == "groupby" else np.asarray(out)
+
+
+@pytest.mark.parametrize("path", ["run_query", "run_queries"])
+@pytest.mark.parametrize("algo", sorted(_ALGO_SPECS))
+def test_meshless_default_matches_oracle(algo, path):
+    """Every single-table algorithm, at an m its lane count does not
+    divide, answers exactly through both entry points, on two-pass at
+    the rule's lane count (HAVING: one lane, the scan)."""
+    from repro.query import run_queries
+
+    t = _algo_table()
+    specs = _ALGO_SPECS[algo]
+    lanes = core.meshless_lanes(algo, _M)
+    assert _M % lanes or (algo == "having" and lanes == 1)
+    outs = ([run_query(s, t) for s in specs] if path == "run_query"
+            else run_queries(specs, t))
+    for spec, out in zip(specs, outs):
+        want, got = _oracle(spec, t), _answer(spec, out["output"])
+        if isinstance(want, np.ndarray):
+            np.testing.assert_array_equal(got, want)
+        else:
+            assert got == want, spec
+        assert out["report"].meta["mode"] == "two_pass"
+        assert out["report"].meta["shards"] == lanes
+
+
+def test_meshless_default_reports_rule_lanes():
+    """The engagement counters: a meshless run_query runs two-pass on
+    exactly meshless_lanes(m) lanes (Q1's m: 770 lanes)."""
+    t = _algo_table()
+    r = run_query(_ALGO_SPECS["groupby"][0], t)
+    assert r["report"].meta["mode"] == "two_pass"
+    assert r["report"].meta["shards"] == core.meshless_lanes("groupby", _M)
+    assert core.meshless_lanes("groupby", 591_788) == 770
+
+
+def test_meshless_default_takes_no_timing(monkeypatch):
+    """The lane rule reads no clock: with merge-cost calibration and the
+    engine's timer made to raise, a meshless query still returns."""
+    from repro.core import engine
+
+    def boom(*a, **k):
+        raise AssertionError("the meshless default timed something")
+
+    monkeypatch.setattr(engine, "calibrate_merge_cost", boom)
+    monkeypatch.setattr(engine, "_time_us", boom)
+    t = _algo_table()
+    for algo in ("groupby", "distinct"):
+        spec = _ALGO_SPECS[algo][0]
+        assert _answer(spec, run_query(spec, t)["output"]) \
+            == _oracle(spec, t)
+
+
+@pytest.mark.parametrize("algo", ["distinct", "skyline"])
+def test_meshless_default_chunks_a_large_apply(algo, monkeypatch):
+    """Where the unchunked [S, n, S·w(, D)] compare exceeds the element
+    cap, the default applies in blocks within it; the mask equals the
+    unchunked two-pass mask at the same lanes."""
+    from repro.core import engine, planner
+
+    cap = 1 << 17
+    monkeypatch.setattr(planner, "APPLY_CHUNK_ELEMENTS", cap)
+    calls = []
+    real = engine._apply_chunked
+
+    def spy(*a, **k):
+        calls.append(a[-1])
+        return real(*a, **k)
+
+    monkeypatch.setattr(engine, "_apply_chunked", spy)
+    t = _algo_table()
+    spec = _ALGO_SPECS[algo][0]
+    lanes = core.meshless_lanes(algo, _M)
+    n = -(-_M // lanes)
+    dims = 2 if algo == "skyline" else 1
+    per_entry = lanes * lanes * spec.params["w"] * dims
+    assert n * per_entry > cap  # unchunked would exceed the cap
+    r = run_query(spec, t)
+    assert len(calls) == 1 and calls[0] < n
+    assert calls[0] * per_entry <= cap
+    stream = (jnp.stack([t.cols["a"], t.cols["b"]], axis=-1)
+              if algo == "skyline" else t.cols["k"])
+    whole = core.engine_prune(algo, stream, mode="two_pass",
+                               shards=lanes, obs="off", **spec.params)
+    assert len(calls) == 1  # the reference ran unchunked
+    np.testing.assert_array_equal(np.asarray(r["keep"]),
+                                  np.asarray(whole.keep))
+    want, got = _oracle(spec, t), _answer(spec, r["output"])
+    if isinstance(want, np.ndarray):
+        np.testing.assert_array_equal(got, want)
+    else:
+        assert got == want

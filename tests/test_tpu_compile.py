@@ -127,3 +127,18 @@ def test_engine_two_pass_compiles_at_sf1(one_chip):
                                      shards=64, obs="off", N=100, w=8).keep
     compiled = _compile(fn, [((SF1_ROWS,), f32)], one_chip)
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("algo,args,params", [
+    ("groupby", [((591_788,), u32), ((591_788,), i32)], dict(d=8, w=4)),
+    ("distinct", [((SF1_ROWS,), u32)], dict(d=4096, w=4))],
+    ids=["groupby_q1_sf01", "distinct_sf1"])
+def test_meshless_default_compiles(one_chip, algo, args, params):
+    """The meshless run_query default at its lane rule: Q1's GROUP BY
+    (770 lanes) and an SF1 DISTINCT (256 lanes, chunked apply)."""
+    shapes = [jax.ShapeDtypeStruct(s, dt) for s, dt in args]
+    plan = core.meshless_plan(algo, shapes, [params])
+    fn = lambda *xs: jax.tree_util.tree_leaves(core.execute_plan(
+        algo, *xs, plan=plan, obs="off", **params))
+    compiled = _compile(fn, args, one_chip)
+    assert compiled.memory_analysis() is not None
